@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (capacity_engine, density, large_cluster, model_perf,
                    prediction, qos_coldstart, roofline_report,
                    scheduling_cost)

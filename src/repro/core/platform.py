@@ -42,7 +42,7 @@ from .capacity import M_MAX_DEFAULT
 from .cluster import Cluster, Node
 from .events import EventHub, JsonlObserver, Observer
 from .interference import NodeResources
-from .prediction_service import INFERENCE_ENGINES, get_schema
+from .prediction_service import INFERENCE_ENGINES, capacity_path, get_schema
 from .profiles import FunctionSpec
 from .registry import Registry
 from .scheduler import (BaseScheduler, SchedulerBuildContext,
@@ -301,8 +301,8 @@ class PredictionSection:
     n_train: int = 2000
     n_trees: int = 24
     max_depth: int = 8
-    #: RFR inference engine override (numpy / jax / pallas); None keeps
-    #: the predictor's default
+    #: RFR inference engine override (numpy / jax / pallas); None takes
+    #: the backend's path (``prediction_service.capacity_path``)
     engine: Optional[str] = None
     online_retrain: bool = False
     retrain_every: Optional[int] = None
@@ -806,9 +806,11 @@ class Platform:
             if isinstance(simulation, CellSimulation) else \
             [s for s in (simulation.scheduler.prediction_service,)
              if s is not None]
+        engine, drain = capacity_path(p.engine)
         for service in services:
-            if p.engine is not None:
-                service.set_engine(p.engine)
+            if engine is not None:
+                service.set_engine(engine)
+            service.cfg = dataclasses.replace(service.cfg, drain=drain)
             service.add_retrain_listener(hub.on_retrain)
         # telemetry section: registry + observer + span tracer.  The
         # None default resolves against the *external* observers, so a

@@ -74,8 +74,24 @@ INFERENCE_ENGINES = ("numpy", "jax", "pallas")
 #: capacity-drain strategies: "host" is the chunked early-exit m-sweep
 #: (numpy rows shipped to the predictor once per chunk round), "device"
 #: the fused single-pass sweep (one padded scenario tensor, one
-#: ``rfr_sweep_op`` launch, capacities gathered device-side)
+#: ``rfr_sweep_op`` launch per drain of new scenarios)
 DRAIN_MODES = ("host", "device")
+
+
+def capacity_path(engine: Optional[str]) -> Tuple[Optional[str], str]:
+    """The platform's capacity-solve path, ``(engine, drain)``, for an
+    optional explicit engine.  On a TPU the forest runs on the chip: no
+    engine means the compiled Pallas sweep in the device drain, ``jax``
+    drains on the device too, and ``numpy`` keeps the host drain.  On
+    the CPU backend the drain stays on the host and ``None`` keeps the
+    predictor's engine (numpy), so every CPU parity gate sees the path
+    it always had."""
+    import jax
+    if jax.default_backend() != "tpu":
+        return engine, "host"
+    engine = engine or "pallas"
+    return engine, ("host" if engine == "numpy" else "device")
+
 
 Coloc = Dict[str, Tuple[float, float]]
 SigKey = Tuple
@@ -428,6 +444,12 @@ class _Solve:
 _Query = Tuple[Coloc, str, int, Optional[NodeResources]]
 
 
+def _pow2(n: int, floor: int = 1) -> int:
+    """Smallest power of two >= max(n, floor): the device drain pads its
+    shapes to these buckets so that repeat drains reuse compiled code."""
+    return max(floor, 1 << max(n - 1, 0).bit_length())
+
+
 class PredictionService:
     """Owns the forest, the feature schema, batched capacity solving, the
     colocation-signature cache, and epoch/retrain bookkeeping; see module
@@ -456,11 +478,6 @@ class PredictionService:
         #: telemetry is enabled)
         self.tracer = NULL_TRACER
         self._cache: Dict[SigKey, Tuple[int, int]] = {}  # key -> (epoch, cap)
-        # device-resident signature cache: solved capacities live in one
-        # growing device vector; repeat signatures resolve as a gather
-        self._dev_slots: Dict[SigKey, int] = {}          # key -> slot index
-        self._dev_caps = None                            # jnp (n_slots,) i32
-        self._interpret: Optional[bool] = None           # pallas off-TPU
         self._epoch = predictor.retrain_count
         self._pending_samples = 0
         self._retrain_listeners: List = []
@@ -482,7 +499,7 @@ class PredictionService:
     def set_engine(self, name: str):
         """Select the RFR inference engine for every prediction issued
         through this service (numpy / jax / pallas, the last routing
-        through the VMEM-resident ``kernels.rfr_inference`` path)."""
+        through the SMEM-resident ``kernels.rfr_inference`` path)."""
         if name not in INFERENCE_ENGINES:
             raise ValueError(f"unknown inference engine {name!r} "
                              f"(have {INFERENCE_ENGINES})")
@@ -554,8 +571,6 @@ class PredictionService:
         state the signatures cannot see has changed)."""
         if self._cache:
             self._cache.clear()
-        self._dev_slots.clear()
-        self._dev_caps = None
         self._shape_margins = None   # re-learn against the new forest
         self.stats.cache_epochs += 1
 
@@ -713,16 +728,15 @@ class PredictionService:
         batch are solved once; rows are billed to the first occurrence
         only.
 
-        ``cfg.drain`` selects the strategy: the chunked host m-sweep
-        below, or the device-resident fused sweep
-        (``_solve_many_device``) — one padded scenario tensor, one
-        kernel pass, no per-chunk host round trips."""
+        Cache hits and duplicates resolve on the host either way;
+        ``cfg.drain`` selects how the unique scenarios are solved: the
+        chunked host m-sweep below, or the fused device sweep
+        (``_sweep_device``) — one padded scenario tensor, one kernel
+        pass, no per-chunk host round trips."""
         norm: List[_Query] = [q if len(q) == 4 else (*q, None)
                               for q in queries]
         self._check_epoch()
         self.stats.solves += len(norm)
-        if self.cfg.drain == "device":
-            return self._solve_many_device(norm)
         results: List[Optional[Tuple[int, int]]] = [None] * len(norm)
         unique: Dict[SigKey, _Solve] = {}
         assignment: List[Optional[SigKey]] = [None] * len(norm)
@@ -744,6 +758,8 @@ class PredictionService:
                 self.stats.unique_solves += 1
             assignment[i] = key
 
+        if self.cfg.drain == "device":
+            self._sweep_device(list(unique.values()))
         active = [s for s in unique.values() if not s.done]
         size = self.cfg.chunk_init if self.cfg.early_exit else \
             max((s.m_max for s in active), default=1)
@@ -773,140 +789,77 @@ class PredictionService:
             billed.add(key)
         return results  # type: ignore[return-value]
 
-    # -- device-resident drain (the fused Pallas/jnp m-sweep) -------------
+    # -- device drain (the fused Pallas/jnp m-sweep) -----------------------
 
-    def _pallas_interpret(self) -> bool:
-        """Pallas kernels run compiled on TPU, interpret-mode anywhere
-        else (the CPU validation path)."""
-        if self._interpret is None:
-            try:
-                import jax
-                self._interpret = jax.default_backend() != "tpu"
-            except Exception:          # pragma: no cover - no jax at all
-                self._interpret = True
-        return self._interpret
-
-    def _solve_many_device(self, norm: List[_Query]
-                           ) -> List[Tuple[int, int]]:
-        """Device-resident capacity solving: the whole drain's candidate
-        feature matrix is assembled as ONE padded (S, M, R, F) jnp
-        tensor and the full m-sweep runs in a single fused forest pass
-        (``kernels.ops.rfr_sweep_op``) that returns max-admissible m per
-        scenario — no host round-trip per chunk, host work O(unique
-        signatures) instead of O(nodes x chunk rounds).
+    def _sweep_device(self, solves: List[_Solve]) -> None:
+        """Solve a drain's unique scenarios in one fused device pass: the
+        whole drain is ONE padded (S, M, R, F) tensor and the full
+        m-sweep runs in a single forest pass (``kernels.ops.rfr_sweep_op``)
+        that returns the max-admissible m per scenario — no host round
+        trip per chunk, host work O(unique signatures).  Each solve is
+        billed its full sweep (m_max x R rows).
 
         Row assembly stays in the float64 numpy ``_Template.build`` —
-        the solver's bit-compatibility contract (device rows are the
-        host oracle's rows, so capacity tables are bit-identical by
-        construction); everything after the one transfer — forest
-        descent, QoS comparison, the running all-pass reduction over m,
-        and cached-capacity resolution (a gather over the device-side
-        capacity vector keyed by colocation signature) — is
-        device-resident and jitted.  ``predictor.engine == "pallas"``
-        routes to the fused Pallas kernel, anything else to the jnp
-        gather sweep."""
+        device rows are the host oracle's rows — and the QoS bounds
+        become tree-sum limits (``rfr_inference.sweep_limits``) with the
+        host's own division and exp, so the capacities equal the host
+        drain's.  S (at least one 128-lane block), M and R are padded to
+        powers of two, so repeat drains reuse compiled code.
+        ``predictor.engine == "pallas"`` runs the fused Pallas kernel,
+        ``"jax"`` the jnp gather sweep; the numpy engine has no device
+        drain."""
         from ..kernels import ops
+        from ..kernels.rfr_inference import LANES, sweep_limits
         import jax.numpy as jnp
 
-        t0 = time.perf_counter()
-        n = len(norm)
-        if n == 0:
-            return []
-        persist = self.cfg.cache
-        # next free slot = device-vector length, NOT len(_dev_slots):
-        # re-solves (host entry evicted) overwrite their slot and leave
-        # an orphan element behind, so the dict can run shorter than
-        # the vector — handing out len(_dev_slots) would collide
-        base = int(self._dev_caps.shape[0]) \
-            if persist and self._dev_caps is not None else 0
-        # key -> (template, m_max, slot, first query index)
-        new: Dict[SigKey, Tuple[_Template, int, int, int]] = {}
-        slot_ids = np.zeros(n, np.int32)
-        first_rows = [0] * n
-        for i, (coloc, fn, m_max, node_res) in enumerate(norm):
-            key = self.signature(coloc, fn, m_max, node_res)
-            if persist:
-                slot = self._dev_slots.get(key)
-                if slot is not None and self._cache_get(key) is not None:
-                    slot_ids[i] = slot
-                    self.stats.cache_hits += 1
-                    continue
-            ent = new.get(key)
-            if ent is not None:
-                self.stats.coalesced_dupes += 1
-                slot_ids[i] = ent[2]
-                continue
-            tmpl = _Template(self.store, self.qos, self.specs, coloc, fn,
-                             self.schema, node_res,
-                             self.qos_bound_scale(node_res))
-            slot = base + len(new)
-            new[key] = (tmpl, m_max, slot, i)
-            slot_ids[i] = slot
-            first_rows[i] = max(m_max, 0) * tmpl.rows_per_m
-            self.stats.unique_solves += 1
-
-        caps_new = None
-        if new:
-            with self.tracer.span("device_sweep", stats=self.stats) as sp:
-                F = self.schema.n_features
-                S = len(new)
-                Mp = max(max(mm for _t, mm, _s, _i in new.values()), 1)
-                Rp = max(t.rows_per_m for t, _mm, _s, _i in new.values())
-                X = np.zeros((S, Mp, Rp, F), np.float32)
-                # +inf bound = padded row, passes; -inf = past this
-                # scenario's own m_max, fails (capacity capped there)
-                B = np.full((S, Mp, Rp), np.inf, np.float32)
-                rows_built = 0
-                for j, (tmpl, mm, _slot, _i) in enumerate(new.values()):
-                    R = tmpl.rows_per_m
-                    if mm > 0:
-                        rows, bounds = tmpl.build(np.arange(1, mm + 1))
-                        X[j, :mm, :R, :] = rows.reshape(mm, R, F)
-                        B[j, :mm, :R] = bounds.reshape(mm, R)
-                    B[j, max(mm, 0):, :] = -np.inf
-                    rows_built += max(mm, 0) * R
-                feat, thr, leaf = self.predictor.model.device_arrays()
-                caps_new = ops.rfr_sweep_op(
-                    jnp.asarray(X), jnp.asarray(B), feat, thr, leaf,
-                    use_pallas=(self.predictor.engine == "pallas"),
-                    interpret=self._pallas_interpret(),
-                    log_target=self.predictor.log_target)
-                self.stats.rows_built += rows_built
-                self.stats.predict_calls += 1
-                if sp is not None:
-                    sp.attrs["scenarios"] = S
-                    sp.attrs["rows"] = rows_built
-                    sp.attrs["padded_shape"] = [S, Mp, Rp, F]
-            if persist:
-                self._dev_caps = caps_new if self._dev_caps is None \
-                    else jnp.concatenate([self._dev_caps, caps_new])
-
-        # resolve every query with one device-side gather
-        all_caps = self._dev_caps if persist else caps_new
-        caps_host = np.asarray(jnp.take(all_caps, jnp.asarray(slot_ids)))
-        if persist:
-            for key, (_t, _mm, slot, i) in new.items():
-                self._dev_slots[key] = slot
-                self._cache_put(key, int(caps_host[i]))
-            self._dev_evict()
-        if new:
-            self.predictor.record_inference(
-                rows_built, time.perf_counter() - t0)
-        return [(int(caps_host[i]), first_rows[i]) for i in range(n)]
-
-    def _dev_evict(self):
-        """Bound the device capacity vector like the host cache: drop
-        oldest slots past ``max_cache_entries`` and compact the
-        survivors with one gather."""
-        import jax.numpy as jnp
-        excess = len(self._dev_slots) - self.cfg.max_cache_entries
-        if excess <= 0:
+        engine = self.predictor.engine
+        if engine not in ("jax", "pallas"):
+            raise ValueError(
+                f"the device drain runs the jax or pallas engine, not "
+                f"{engine!r}; the numpy engine drains on the host")
+        if not solves:
             return
-        keep = list(self._dev_slots)[excess:]
-        idx = jnp.asarray(np.asarray(
-            [self._dev_slots[k] for k in keep], np.int32))
-        self._dev_caps = jnp.take(self._dev_caps, idx)
-        self._dev_slots = {k: i for i, k in enumerate(keep)}
+        t0 = time.perf_counter()
+        with self.tracer.span("device_sweep", stats=self.stats) as sp:
+            F = self.schema.n_features
+            S = len(solves)
+            Sp = _pow2(S, LANES)
+            Mp = _pow2(max(s.m_max for s in solves))
+            Rp = _pow2(max(s.tmpl.rows_per_m for s in solves))
+            feat, thr, leaf = self.predictor.model.device_arrays()
+            limits = sweep_limits(
+                np.concatenate([s.tmpl.bounds_per_m for s in solves]),
+                int(feat.shape[0]), self.predictor.log_target)
+            X = np.zeros((Sp, Mp, Rp, F), np.float32)
+            # +inf limit = padded row, passes; -inf = past this
+            # scenario's own m_max, fails (capacity capped there);
+            # padded scenarios pass everything and are never read
+            L = np.full((Sp, Mp, Rp), np.inf, np.float32)
+            off = 0
+            for j, s in enumerate(solves):
+                R, mm = s.tmpl.rows_per_m, max(s.m_max, 0)
+                if mm:
+                    rows, _bounds = s.tmpl.build(np.arange(1, mm + 1))
+                    X[j, :mm, :R, :] = rows.reshape(mm, R, F)
+                    L[j, :mm, :R] = limits[off:off + R]
+                L[j, mm:, :] = -np.inf
+                off += R
+                s.rows = mm * R
+            caps = np.asarray(ops.rfr_sweep_op(
+                jnp.asarray(X), jnp.asarray(L), feat, thr, leaf,
+                use_pallas=(engine == "pallas")))
+            rows_built = sum(s.rows for s in solves)
+            self.stats.rows_built += rows_built
+            self.stats.predict_calls += 1
+            if sp is not None:
+                sp.attrs["scenarios"] = S
+                sp.attrs["rows"] = rows_built
+                sp.attrs["padded_shape"] = [Sp, Mp, Rp, F]
+        for s, cap in zip(solves, caps):
+            s.capacity = int(cap)
+            s.done = True
+        self.predictor.record_inference(rows_built,
+                                        time.perf_counter() - t0)
 
     # -- node-level API (the async-update path) ---------------------------
 

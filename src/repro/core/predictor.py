@@ -15,10 +15,14 @@ Training is plain numpy CART (variance-reduction splits, bootstrap rows,
 sqrt-feature bagging) — profiling/training nodes are offline, so training
 cost is off the scheduling path.  Inference has three engines:
 
-  * ``numpy``  — vectorized level-synchronous descent (simulator default),
+  * ``numpy``  — vectorized level-synchronous descent (the host oracle,
+                 and the default on the CPU backend),
   * ``jax``    — jnp gathers (jit),
-  * ``pallas`` — the VMEM-resident forest kernel
+  * ``pallas`` — the SMEM-resident forest kernel
                  (``repro.kernels.rfr_inference``), the TPU hot path.
+
+All three add the tree values in numpy's pairwise order, so their
+predictions are bit-identical.
 
 The forest is flattened to *complete* depth-D arrays so all engines share
 one layout.  Also ships the Fig-16 comparison zoo (linear/ridge/ESP-style
@@ -217,9 +221,11 @@ class RandomForestRegressor:
         import jax.numpy as jnp
         from ..kernels import ops
         feat, thr, leaf = self.device_arrays()
-        out = ops.rfr_op(jnp.asarray(X), feat, thr, leaf,
-                         use_pallas=(engine == "pallas"))
-        return np.asarray(out)
+        sums = ops.rfr_op(jnp.asarray(X), feat, thr, leaf,
+                          use_pallas=(engine == "pallas"))
+        # the device adds tree values in numpy's order; dividing here
+        # keeps the mean's rounding the numpy engine's too
+        return np.asarray(sums) / np.float32(self.n_trees)
 
     def _predict_numpy(self, X: np.ndarray) -> np.ndarray:
         a = self.arrays

@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import InputShape, ModelConfig
@@ -38,6 +38,19 @@ from .sharding import (axis_size, batch_pspecs, cache_shardings, dp_axes,
 # random draw in this repo must use the partitionable stream, or states
 # initialized through different entry points stop agreeing.
 jax.config.update("jax_threefry_partitionable", True)
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The step builders shard by placement (in/out shardings) and
+    steer activations with sharding constraints, which is the Auto-axis
+    model.  ``jax.make_mesh`` builds Explicit axes by default (JAX 0.7
+    on), where those constraints and untyped gathers such as the
+    embedding lookup raise; rebuild such a mesh with Auto axes."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
 
 # ---------------------------------------------------------------------------
 # Abstract trees
@@ -202,6 +215,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, shape: InputShape,
     (half the wire + HBM traffic for every weight gather; the model's
     per-use ``astype`` then no-ops)."""
     opt_cfg = opt_cfg or adamw.AdamWConfig()
+    mesh = auto_axes(mesh)
     disp, hints = _dispatch_for(cfg, shape, mesh, dispatch)
     hints = {**_model_hints(cfg, shape, mesh), **hints,
              **(extra_hints or {})}
@@ -271,6 +285,7 @@ def make_prefill_step(cfg: ModelConfig, mesh: Mesh, shape: InputShape,
                       dispatch: Optional[str] = None,
                       param_dtype=jnp.float32,
                       extra_hints: Optional[dict] = None) -> StepBundle:
+    mesh = auto_axes(mesh)
     disp, hints = _dispatch_for(cfg, shape, mesh, dispatch)
     hints = {**_model_hints(cfg, shape, mesh), **hints,
              **(extra_hints or {})}
@@ -325,6 +340,7 @@ def make_decode_step(cfg: ModelConfig, mesh: Mesh, shape: InputShape,
     """One serve_step: each batch element appends one token against a KV /
     state cache of length seq_len.  ``cache_l_model`` shards the cache
     length dim over the "model" axis (flash-decoding)."""
+    mesh = auto_axes(mesh)
     disp, hints = _dispatch_for(cfg, shape, mesh, dispatch)
     hints = {**_model_hints(cfg, shape, mesh), **hints,
              **(extra_hints or {})}

@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", ""))
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input shape x mesh) cell this lowers and
@@ -19,8 +14,13 @@ Usage:
   python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k \
       --mesh single [--out benchmarks/artifacts/dryrun] [--opts ...]
   python -m repro.launch.dryrun --all --mesh both     # every cell, one proc
+
+``main()`` gives the CPU backend 512 placeholder devices; a caller of
+``run_cell`` sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512``
+before the backend starts.
 """
 import argparse
+import os
 import json
 import sys
 import time
@@ -51,6 +51,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     from ..configs.base import SHAPE_BY_NAME, cell_is_runnable, get_config
     from ..distributed.steps import make_step_bundle
     from ..optim.adamw import AdamWConfig
+    from .mesh import PRODUCTION_DEVICE_KIND
     from .roofline import collective_bytes, roofline_terms
 
     cfg = get_config(arch)
@@ -122,7 +123,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     from .roofline import hlo_stats
     stats = hlo_stats(hlo)   # loop-corrected (cost_analysis counts loop
     #                          bodies once; see roofline.hlo_stats)
-    terms = roofline_terms(stats, coll, mesh.size, cfg, shape)
+    terms = roofline_terms(stats, coll, mesh.size, cfg, shape,
+                           device_kind=PRODUCTION_DEVICE_KIND)
     terms["xla_flops_unscaled"] = cost.get("flops")
     terms["xla_bytes_unscaled"] = cost.get("bytes accessed")
 
@@ -143,6 +145,11 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
 
 def main() -> int:
+    # placeholder devices for the production meshes; read when the
+    # backend starts, which nothing before this point has done
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", ""))
     from ..configs.base import SHAPES, cell_is_runnable, get_config, \
         list_archs
 

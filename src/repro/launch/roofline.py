@@ -1,11 +1,11 @@
 """Roofline terms from a compiled dry-run artifact.
 
 Three terms per (arch x shape x mesh) cell, all in seconds-per-step on
-TPU v5e constants (mesh.py):
+the peaks of the target chip (``mesh.PEAKS``, keyed by device kind):
 
-    compute    = HLO_FLOPs_per_device / PEAK_FLOPS_BF16
-    memory     = HLO_bytes_per_device / HBM_BW
-    collective = wire_bytes_per_device / ICI_BW
+    compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s
+    memory     = HLO_bytes_per_device / HBM bytes/s
+    collective = wire_bytes_per_device / ICI bytes/s per link
 
 ``cost_analysis()`` runs on the SPMD-partitioned module, so its FLOPs /
 bytes are already per-device.  Collective bytes are NOT in cost_analysis:
@@ -23,7 +23,7 @@ import re
 from typing import Dict, Optional
 
 from ..configs.base import InputShape, ModelConfig
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from .mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -275,14 +275,18 @@ def model_flops(cfg: ModelConfig, shape: InputShape) -> float:
 
 def roofline_terms(cost: dict, coll: Dict[str, float], n_devices: int,
                    cfg: Optional[ModelConfig] = None,
-                   shape: Optional[InputShape] = None) -> dict:
+                   shape: Optional[InputShape] = None, *,
+                   device_kind: str) -> dict:
+    """Roofline terms on ``device_kind``'s peaks (``ValueError`` for a
+    chip without published peaks)."""
+    peaks = chip_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     bytes_hbm = float(cost.get("bytes accessed", 0.0))
     wire = float(sum(v for k, v in coll.items() if not k.startswith("_")))
     terms = {
-        "compute_s": flops / PEAK_FLOPS_BF16,
-        "memory_s": bytes_hbm / HBM_BW,
-        "collective_s": wire / ICI_BW,
+        "compute_s": flops / peaks["flops_bf16"],
+        "memory_s": bytes_hbm / peaks["hbm_bw"],
+        "collective_s": wire / peaks["ici_bw"],
         "hlo_flops_per_device": flops,
         "hlo_bytes_per_device": bytes_hbm,
         "wire_bytes_per_device": wire,
@@ -300,5 +304,5 @@ def roofline_terms(cost: dict, coll: Dict[str, float], n_devices: int,
         step_s = max(terms["compute_s"], terms["memory_s"],
                      terms["collective_s"])
         terms["roofline_frac"] = (mf / n_devices / max(step_s, 1e-30)
-                                  / PEAK_FLOPS_BF16)
+                                  / peaks["flops_bf16"])
     return terms

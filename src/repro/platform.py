@@ -18,6 +18,13 @@ their collaborators only through the capability protocols
 from .core.platform import *            # noqa: F401,F403
 from .core.platform import __all__      # noqa: F401
 
-if __name__ == "__main__":
+
+def main() -> None:
+    from .compile_cache import enable_compile_cache
     from .core.platform import smoke
+    enable_compile_cache()
     smoke()
+
+
+if __name__ == "__main__":
+    main()

@@ -25,33 +25,15 @@ def _deterministic_global_rng():
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
-        "tpu_only: real-hardware Pallas path (interpret=False) that the "
-        "CPU interpret mode cannot run; auto-skipped off-TPU")
-    config.addinivalue_line(
-        "markers",
         "slow: large-cluster / long-trace tests kept out of tier-1; run "
         "with RUN_SLOW=1 (scripts/verify.sh --full)")
 
 
-def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def pytest_collection_modifyitems(config, items):
-    if os.environ.get("RUN_SLOW") != "1":
-        skip_slow = pytest.mark.skip(
-            reason="slow: set RUN_SLOW=1 (or scripts/verify.sh --full)")
-        for item in items:
-            if "slow" in item.keywords:
-                item.add_marker(skip_slow)
-    if _on_tpu():
+    if os.environ.get("RUN_SLOW") == "1":
         return
-    skip = pytest.mark.skip(
-        reason="tpu_only: needs real TPU (Pallas interpret=False)")
+    skip_slow = pytest.mark.skip(
+        reason="slow: set RUN_SLOW=1 (or scripts/verify.sh --full)")
     for item in items:
-        if "tpu_only" in item.keywords:
-            item.add_marker(skip)
+        if "slow" in item.keywords:
+            item.add_marker(skip_slow)

@@ -72,7 +72,7 @@ def restore_engine(world):
     pred.engine = prev
 
 
-def _three_way(world, schema, interpret=True):
+def _three_way(world, schema):
     """Host numpy oracle (full sweep, for stats parity) vs device drains."""
     specs, gt, store, qos, pred = world
     rng = np.random.default_rng(17)
@@ -91,8 +91,6 @@ def _three_way(world, schema, interpret=True):
         dev = CapacityEngine(pred, store, qos, specs,
                              EngineConfig(m_max=m_max, drain="device"),
                              schema=schema)
-        if not interpret:
-            dev._interpret = False
         pred.engine = engine
         dev.update_nodes(nodes, m_max=m_max)
         assert _tables(nodes) == ref_tables, f"capacity mismatch ({engine})"
@@ -180,9 +178,20 @@ def test_device_drain_empty_and_trivial_batches(world, restore_engine):
     assert cap == 0 and rows == 0      # m_max=0: nothing admissible
 
 
+def test_device_drain_refuses_numpy_engine(world, restore_engine):
+    """The device drain runs the jax or pallas engine; it never runs a
+    jnp sweep under the name ``numpy``."""
+    specs, gt, store, qos, pred = world
+    pred.engine = "numpy"
+    dev = CapacityEngine(pred, store, qos, specs,
+                         EngineConfig(drain="device"))
+    with pytest.raises(ValueError, match="numpy engine drains on the host"):
+        dev.solve_many([({}, sorted(specs)[0], 4, None)])
+
+
 def test_device_cache_eviction_compacts_slots(world, restore_engine):
-    """The device capacity vector is bounded like the host cache:
-    oldest slots evicted, survivors compacted, gathers still correct."""
+    """The device drain's signature cache is bounded like the host
+    drain's: oldest entries evicted, survivors still resolve warm."""
     specs, gt, store, qos, pred = world
     names = sorted(specs)
     pred.engine = "jax"
@@ -195,8 +204,7 @@ def test_device_cache_eviction_compacts_slots(world, restore_engine):
     for i, coloc in enumerate(colocs):
         (cap, _r), = dev.solve_many([(dict(coloc), names[0], 6, None)])
         expect[i] = cap
-    assert len(dev._dev_slots) <= 3
-    assert int(dev._dev_caps.shape[0]) == len(dev._dev_slots)
+    assert len(dev._cache) == 3
     # survivors (the 3 newest) still resolve warm with the right values
     for i in (3, 4, 5):
         (cap, rows), = dev.solve_many([(dict(colocs[i]), names[0], 6, None)])
@@ -207,8 +215,8 @@ def test_device_cache_eviction_compacts_slots(world, restore_engine):
 
 
 def test_device_drain_retrain_invalidates(world, restore_engine):
-    """Epoch bump must clear the device-side cache too — a post-retrain
-    gather can never serve a pre-retrain capacity."""
+    """Epoch bump must clear the device drain's cache too — a
+    post-retrain solve can never serve a pre-retrain capacity."""
     specs, gt, store, qos, pred = world
     p2 = PerfPredictor(n_trees=6, max_depth=6, seed=3)
     X, y = generate_dataset(specs, gt, store, qos, 300, seed=9)
@@ -219,7 +227,7 @@ def test_device_drain_retrain_invalidates(world, restore_engine):
     names = sorted(specs)
     q = ({names[1]: (2.0, 0.0)}, names[0], 8, None)
     dev.solve_many([q])
-    assert dev._dev_slots and dev._dev_caps is not None
+    assert dev._cache
     p2.add_sample(X[0], float(y[0]), retrain=False)
     p2.retrain()
     (cap, rows), = dev.solve_many([q])
@@ -229,9 +237,3 @@ def test_device_drain_retrain_invalidates(world, restore_engine):
                                 EngineConfig(m_max=8)).capacity(
         {names[1]: (2.0, 0.0)}, names[0], 8)
     assert cap == cap_ref
-
-
-@pytest.mark.tpu_only
-def test_three_way_drain_parity_compiled(world, restore_engine):
-    """The compiled (interpret=False) Pallas sweep on real hardware."""
-    _three_way(world, schema=2, interpret=False)

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py
-oracles, plus ops.py wrappers vs the model layer's expectations."""
+oracles, plus ops.py wrappers vs the model layer's expectations.  The
+compiled kernels are checked against a described TPU in
+``test_tpu_compile.py``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,8 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.rfr_inference import rfr_forest_apply
+from repro.kernels.rfr_inference import (rfr_forest_apply, sweep_limits,
+                                         tree_sum)
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -63,6 +66,20 @@ def test_flash_attention_noncausal():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_pallas_interpret_follows_backend(monkeypatch, backend, interpret):
+    """One decision: compiled on a TPU, interpreted on the CPU backend,
+    and no silent interpreter anywhere else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no Pallas path"):
+            ops.pallas_interpret()
+    else:
+        assert ops.pallas_interpret() is interpret
+
+
 def test_attention_op_gqa_expansion():
     """ops.attention_op accepts (B, S, H, D) GQA layouts."""
     key = jax.random.PRNGKey(3)
@@ -71,7 +88,7 @@ def test_attention_op_gqa_expansion():
     q = _rand(ks[0], (B, S, Hq, D), jnp.float32)
     k = _rand(ks[1], (B, S, Hkv, D), jnp.float32)
     v = _rand(ks[2], (B, S, Hkv, D), jnp.float32)
-    out_pl = ops.attention_op(q, k, v, use_pallas=True, interpret=True)
+    out_pl = ops.attention_op(q, k, v, use_pallas=True)
     out_ref = ops.attention_op(q, k, v, use_pallas=False)
     assert out_pl.shape == (B, S, Hq, D)
     np.testing.assert_allclose(np.asarray(out_pl), np.asarray(out_ref),
@@ -182,27 +199,45 @@ def test_rfr_forest_matches_ref(N, T, depth, F):
                            jnp.asarray(thr), jnp.asarray(leaf),
                            interpret=True)
     want = ref.rfr_forest_ref(x, feat, thr, leaf)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got) / np.float32(T),
+                               np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.tpu_only
-def test_rfr_forest_real_kernel_cluster_batch():
-    """The compiled (interpret=False) VMEM-resident forest kernel at a
-    cluster-scale batch — the path CapacityEngine drains feed on TPU."""
-    rng = np.random.default_rng(2)
-    T, depth, F = 32, 8, 31
+@pytest.mark.parametrize("T", [1, 5, 8, 24, 32, 100, 200])
+def test_tree_sum_is_numpy_mean_order(T):
+    """The kernels' tree sums follow numpy's pairwise f32 order, so sum
+    / T is bit-identical to the numpy engine's ``vals.mean(axis=1)``
+    (a sequential sum differs in about half of all rows)."""
+    rng = np.random.default_rng(T)
+    vals = (rng.standard_normal((4000, T))
+            * rng.uniform(0.1, 10.0, (4000, 1))).astype(np.float32)
+    got = tree_sum(
+        lambda t: jax.lax.dynamic_index_in_dim(jnp.asarray(vals), t, 1,
+                                               keepdims=False),
+        T, jnp.zeros((4000,), jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got) / np.float32(T),
+                                  vals.mean(axis=1))
+
+
+def test_rfr_forest_apply_bit_matches_numpy_engine():
+    """Compare-and-select descent plus pairwise tree sums: the Pallas
+    engine's predictions equal the numpy engine's exactly, across a
+    multi-block grid and +inf (always-left) thresholds."""
+    from repro.core.predictor import ForestArrays, RandomForestRegressor
+    rng = np.random.default_rng(11)
+    T, depth, F, N = 24, 6, 31, 2500
     NN = (1 << depth) - 1
-    x = rng.standard_normal((2048, F)).astype(np.float32)
-    feat = rng.integers(0, F, (T, NN)).astype(np.int32)
+    m = RandomForestRegressor(n_trees=T, max_depth=depth)
     thr = rng.standard_normal((T, NN)).astype(np.float32)
-    leaf = rng.standard_normal((T, 1 << depth)).astype(np.float32)
-    got = rfr_forest_apply(jnp.asarray(x), jnp.asarray(feat),
-                           jnp.asarray(thr), jnp.asarray(leaf),
-                           interpret=False)
-    want = ref.rfr_forest_ref(x, feat, thr, leaf)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    thr[:, NN // 2:] = np.inf
+    m.arrays = ForestArrays(rng.integers(0, F, (T, NN)).astype(np.int32),
+                            thr, rng.standard_normal(
+                                (T, 1 << depth)).astype(np.float32))
+    x = rng.standard_normal((N, F)).astype(np.float32)
+    np.testing.assert_array_equal(m.predict(x, engine="pallas"),
+                                  m.predict(x, engine="numpy"))
+    np.testing.assert_array_equal(m.predict(x, engine="jax"),
+                                  m.predict(x, engine="numpy"))
 
 
 def test_rfr_forest_apply_empty_batch():
@@ -237,8 +272,8 @@ def test_rfr_forest_apply_partial_blocks(N, block_n):
                            block_n=block_n, interpret=True)
     want = ref.rfr_forest_ref(x, feat, thr, leaf)
     assert got.shape == (N,)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got) / np.float32(T),
+                               np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -270,10 +305,10 @@ def _sweep_case(seed, S=7, M=6, R=3, T=8, depth=4, F=9):
 @pytest.mark.parametrize("log_target", [False, True])
 def test_rfr_capacity_sweep_matches_ref(use_pallas, log_target):
     x, bounds, feat, thr, leaf = _sweep_case(5)
-    got = ops.rfr_sweep_op(jnp.asarray(x), jnp.asarray(bounds),
+    limits = sweep_limits(bounds, feat.shape[0], log_target)
+    got = ops.rfr_sweep_op(jnp.asarray(x), jnp.asarray(limits),
                            jnp.asarray(feat), jnp.asarray(thr),
-                           jnp.asarray(leaf), use_pallas=use_pallas,
-                           interpret=True, log_target=log_target)
+                           jnp.asarray(leaf), use_pallas=use_pallas)
     want = ref.rfr_capacity_sweep_ref(x, bounds, feat, thr, leaf,
                                       log_target=log_target)
     assert got.dtype == jnp.int32
@@ -282,16 +317,44 @@ def test_rfr_capacity_sweep_matches_ref(use_pallas, log_target):
 
 def test_rfr_capacity_sweep_block_partitioning():
     """Scenario-block size must not change results (padded scenarios
-    pass trivially and are sliced off)."""
+    pass trivially and are sliced off).  Blocks are whole 128-lane
+    columns: 300 scenarios run as grids of 3, 2, 1 and 1 steps."""
     from repro.kernels.rfr_inference import rfr_capacity_sweep
-    x, bounds, feat, thr, leaf = _sweep_case(6, S=11)
+    x, bounds, feat, thr, leaf = _sweep_case(6, S=300)
     want = ref.rfr_capacity_sweep_ref(x, bounds, feat, thr, leaf)
-    for bs in (1, 3, 11, 64):
-        got = rfr_capacity_sweep(jnp.asarray(x), jnp.asarray(bounds),
+    limits = jnp.asarray(sweep_limits(bounds, feat.shape[0], False))
+    for bs in (128, 256, 384, 1024):
+        got = rfr_capacity_sweep(jnp.asarray(x), limits,
                                  jnp.asarray(feat), jnp.asarray(thr),
                                  jnp.asarray(leaf), block_s=bs,
                                  interpret=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("log_target", [False, True])
+def test_sweep_limits_reproduce_host_verdicts(log_target):
+    """A tree sum passes its limit exactly when the host's prediction
+    (numpy's f32 division, then exp) meets the float64 bound — checked
+    at the limit, one ulp above it, and on random sums."""
+    rng = np.random.default_rng(3)
+    T = 24
+    bounds = np.concatenate([rng.uniform(0.05, 40.0, 200),
+                             [np.inf, -np.inf]])
+    if not log_target:
+        bounds[:200] = rng.uniform(-3.0, 3.0, 200)
+    lim = sweep_limits(bounds, T, log_target)
+    assert lim[-2] == np.inf and lim[-1] == -np.inf
+    fin = lim[:-2]
+    above = np.nextafter(fin, np.float32(np.inf))
+    s = np.concatenate([fin, above,
+                        rng.uniform(-80.0, 80.0, 200).astype(np.float32)])
+    b = np.concatenate([bounds[:-2]] * 3)
+    p = s / np.float32(T)
+    if log_target:
+        p = np.exp(p)
+    host = p.astype(np.float64) <= b
+    np.testing.assert_array_equal(s <= np.concatenate([fin] * 3), host)
+    assert host[:200].all() and not host[200:400].any()
 
 
 @pytest.mark.parametrize("use_pallas", [True, False])
@@ -306,24 +369,10 @@ def test_rfr_capacity_sweep_degenerate_shapes(use_pallas):
     for S, M, R in [(0, 4, 2), (3, 0, 2), (3, 4, 0)]:
         out = ops.rfr_sweep_op(jnp.zeros((S, M, R, F), jnp.float32),
                                jnp.zeros((S, M, R), jnp.float32),
-                               feat, thr, leaf, use_pallas=use_pallas,
-                               interpret=True)
+                               feat, thr, leaf, use_pallas=use_pallas)
         assert out.shape == (S,)
         assert out.dtype == jnp.int32
         assert not np.asarray(out).any()
-
-
-@pytest.mark.tpu_only
-def test_rfr_capacity_sweep_real_kernel():
-    """The compiled (interpret=False) fused sweep at drain scale."""
-    x, bounds, feat, thr, leaf = _sweep_case(8, S=128, M=16, R=4,
-                                             T=32, depth=8, F=31)
-    got = ops.rfr_sweep_op(jnp.asarray(x), jnp.asarray(bounds),
-                           jnp.asarray(feat), jnp.asarray(thr),
-                           jnp.asarray(leaf), use_pallas=True,
-                           interpret=False)
-    want = ref.rfr_capacity_sweep_ref(x, bounds, feat, thr, leaf)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_rfr_op_consistent_with_trained_model():

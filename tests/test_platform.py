@@ -135,6 +135,34 @@ def test_build_mismatched_world_schema(small_world):
         Platform.build(scenario=scenario, config=cfg, world=world)
 
 
+@pytest.mark.parametrize("backend,engine,path", [
+    ("tpu", None, ("pallas", "device")),
+    ("tpu", "numpy", ("numpy", "host")),
+    ("cpu", None, ("numpy", "host")),
+])
+def test_build_takes_the_backends_capacity_path(small_world, monkeypatch,
+                                                backend, engine, path):
+    """On a TPU the capacity solve drains on the chip with the compiled
+    Pallas sweep unless the manifest names an engine, and ``numpy``
+    always drains on the host; the CPU backend keeps the host numpy
+    path.  Only the build runs: the faked backend never compiles."""
+    import jax
+    _cfg, scenario, world = small_world
+    prediction = dict(SMALL["prediction"])
+    if engine is not None:
+        prediction["engine"] = engine
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    prev = world.predictor.engine
+    try:
+        plat = Platform.build(scenario=scenario, world=world, config={
+            **SMALL, "scheduler": {"name": "jiagu"},
+            "prediction": prediction})
+        assert (plat.service.inference_engine,
+                plat.service.cfg.drain) == path
+    finally:
+        world.predictor.engine = prev      # the world is module-shared
+
+
 # ---------------------------------------------------------------------------
 # Registries
 # ---------------------------------------------------------------------------

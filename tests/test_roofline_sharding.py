@@ -67,10 +67,16 @@ def test_roofline_terms_and_bottleneck():
     cost = {"flops": 197e12, "bytes accessed": 819e9 * 2}
     coll = {"all-reduce": 0.0, "all-gather": 0.0, "reduce-scatter": 0.0,
             "all-to-all": 0.0, "collective-permute": 0.0}
-    t = roofline_terms(cost, coll, 256)
+    t = roofline_terms(cost, coll, 256, device_kind="TPU v5 lite")
     assert abs(t["compute_s"] - 1.0) < 1e-6
     assert abs(t["memory_s"] - 2.0) < 1e-6
     assert t["bottleneck"] == "memory"
+
+
+def test_roofline_terms_refuse_unknown_device():
+    """A chip without published peaks is an error, not a v5e default."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms({"flops": 1.0}, {}, 1, device_kind="cpu")
 
 
 def test_model_flops_conventions():
