@@ -12,12 +12,13 @@ cluster's capacity tables per path:
   * engine  — ``CapacityEngine.update_nodes`` (one coalesced drain:
               a handful of batched predictor calls, signature cache,
               vectorized assembly, chunked early-exit m-sweep)
-  * device  — ``EngineConfig(drain="device")``: the whole drain packed
-              into ONE (S, M, R, F) scenario tensor, the full m-sweep
-              fused into a single forest pass (``rfr_sweep_op``; Pallas
-              on TPU, the jnp gather sweep on CPU), capacities resolved
-              by a device-side gather.  A second (warm) drain shows the
-              steady-state cost once the device cache is populated.
+  * device  — ``EngineConfig(drain="device")``: the drain's new
+              scenarios packed into (128, M, R, F) blocks, each block's
+              full m-sweep fused into a single forest pass
+              (``rfr_sweep_op``; Pallas on TPU, the jnp gather sweep on
+              CPU), launch shapes compiled before the timing.  A second
+              (warm) drain shows the steady-state cost once the
+              signature cache is populated.
 
 and assert all resulting capacity tables are identical (the solver's
 bit-compatibility contract).  The legacy O(nodes) path is only run up
@@ -154,17 +155,13 @@ def run(quick: bool = False, bench: bool = False):
         prev_engine = pred.engine
         pred.engine = dev_engine
         try:
-            # warm the jit/Pallas compile for this size's padded shape,
-            # then invalidate so the timed drain re-solves everything
-            device.update_nodes(nodes, m_max=M_MAX)
-            _clear(nodes)
-            device.invalidate()
+            device.warm_device()        # compiles stay out of the timing
             t0 = time.perf_counter()
             device.update_nodes(nodes, m_max=M_MAX)
             device_s = time.perf_counter() - t0
             assert _tables(nodes) == ref, \
                 f"device capacity tables diverged at {n_nodes} nodes"
-            device_calls = device.stats.predict_calls // 2  # minus warm-up
+            device_calls = device.stats.predict_calls
             _clear(nodes)
             t0 = time.perf_counter()
             device.update_nodes(nodes, m_max=M_MAX)

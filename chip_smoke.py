@@ -14,9 +14,11 @@ that each raise on failure:
       engine's semantics, with ``tpu_custom_call`` in their programs;
   (c) the capacity drain of a 4096-node fleet on the device (compiled
       Pallas sweep) against the host numpy oracle: equal tables;
-  (d) ``Platform.build`` end to end: a 1024-node ``jiagu`` burst storm on
-      the chip path the platform selects, against the same world run on
-      the host numpy drain: identical density, QoS and counters;
+  (d) ``Platform.build`` end to end: the first run of a 1024-node
+      ``jiagu`` burst storm on the chip path the platform selects, which
+      must compile nothing its build did not warm, against the same
+      world run on the host numpy drain: identical density, QoS and
+      counters;
   (e) the learned scorer trained on traced ``jiagu-pipeline`` decisions,
       served in one platform run: no stale-epoch serves.
 
@@ -133,6 +135,11 @@ def phase_kernels(cases, compiled: bool = True) -> None:
         host = _host_predict(feat, thr, leaf, xf)
         want = np.asarray(ref.rfr_forest_ref(xf, feat, thr, leaf))
         np.testing.assert_allclose(pred, want, rtol=1e-6, atol=0)
+        # the engines' contract: pallas predictions are the numpy engine's
+        not_equal = int((pred != host).sum())
+        if not_equal:
+            raise AssertionError(f"forest T={T} F={F}: {not_equal} rows "
+                                 "differ from the numpy engine's")
         # the fused sweep: bounds straddle the host's own predictions
         x = rng.standard_normal((S, M_SWEEP, R, F)).astype(np.float32)
         p = np.exp(_host_predict(feat, thr, leaf, x.reshape(-1, F)))
@@ -171,7 +178,7 @@ def phase_kernels(cases, compiled: bool = True) -> None:
         log("kernels", trees=T, features=F, scenarios=S, rows_per_m=R,
             apply_rows=N, apply_ms=apply_ms, sweep_ms=sweep_ms,
             forest_max_rel_err=float(np.max(np.abs(pred - want) / want)),
-            forest_rows_not_bit_equal_numpy=int((pred != host).sum()),
+            forest_rows_not_bit_equal_numpy=not_equal,
             capacity_hist=np.bincount(caps, minlength=M_SWEEP + 1).tolist(),
             ref_checked=with_ref, ref_boundary_cases=near,
             tpu_custom_call=("tpu_custom_call" in hlo_sweep))
@@ -274,26 +281,18 @@ def phase_platform(target_nodes: int, duration_s: int,
         "cells": {"count": 1},
         "telemetry": {"spans": True},
     }
+    # the user's first build and run in this process: the build warms
+    # the device drain's launch shapes, and the run must compile nothing
+    # (a decision slowed by a compile delays its table update)
+    c0 = counter.n
     t0 = time.perf_counter()
     chip = Platform.build(config=manifest)
     build_s = time.perf_counter() - t0
+    build_compiles = counter.n - c0
     svc = chip.service
     path = (svc.inference_engine, svc.cfg.drain)
     if compiled and path != ("pallas", "device"):
         raise AssertionError(f"the platform chose {path} on a TPU")
-    # Async table updates land after the measured inference latency, so
-    # a decision slowed past the 1 s tick by a first-shape compile acts
-    # differently.  A warm-up run compiles the shapes; the compared run
-    # then counts its own compiles (expect none).
-    chip.world.gt.reseed()
-    c0 = counter.n
-    t0 = time.perf_counter()
-    chip.run()
-    warm_s = time.perf_counter() - t0
-    warm_compiles = counter.n - c0
-    chip = Platform.build(scenario=chip.scenario, config=manifest,
-                          world=chip.world)
-    svc = chip.service
     chip.world.gt.reseed()
     c0 = counter.n
     t0 = time.perf_counter()
@@ -317,23 +316,31 @@ def phase_platform(target_nodes: int, duration_s: int,
     fc, fh = _fingerprint(res_chip), _fingerprint(res_host)
     diff = sorted(k for k in fc if fc[k] != fh[k])
     sweep = spans.get("device_sweep", {})
+    host_spans = {r["name"]: r for r in host.span_summary()}
     log("platform", nodes=target_nodes, duration_s=duration_s,
-        path=list(path), build_s=build_s, warmup_run_s=warm_s,
-        warmup_compiles=warm_compiles, chip_run_s=chip_s,
+        path=list(path), build_s=build_s, build_compiles=build_compiles,
+        chip_run_s=chip_s, run_compiles=compiles,
         host_run_s=host_s, drains=svc.stats.predict_calls,
-        unique_solves=svc.stats.unique_solves, compiles=compiles,
+        unique_solves=svc.stats.unique_solves,
         device_sweep_count=sweep.get("count", 0),
         device_sweep_total_ms=sweep.get("total_ms", 0.0),
         capacity_solve_total_ms=spans.get("capacity_solve", {}).get(
             "total_ms", 0.0),
+        host_capacity_solve_total_ms=host_spans.get(
+            "capacity_solve", {}).get("total_ms", 0.0),
         density=res_chip.density,
         qos_violation_rate=res_chip.qos_violation_rate,
         decisions=res_chip.sched.decisions,
+        chip_decision_ms_p50=res_chip.sched.p50_latency_ms,
+        host_decision_ms_p50=res_host.sched.p50_latency_ms,
         chip_decision_ms_p99=res_chip.sched.p99_latency_ms,
         host_decision_ms_p99=res_host.sched.p99_latency_ms,
         chip_inference_rows=res_chip.inference_rows,
         host_inference_rows=res_host.inference_rows,
         identical=not diff, differing=diff)
+    if compiles:
+        raise AssertionError(f"the chip run compiled {compiles} programs "
+                             "that its build did not warm")
     if diff:
         raise AssertionError(f"chip and host runs differ in {diff}")
 

@@ -811,6 +811,10 @@ class Platform:
             if engine is not None:
                 service.set_engine(engine)
             service.cfg = dataclasses.replace(service.cfg, drain=drain)
+            if drain == "device":
+                # compile now: a compile inside a run would delay the
+                # decision that triggered it and change what follows
+                service.warm_device()
             service.add_retrain_listener(hub.on_retrain)
         # telemetry section: registry + observer + span tracer.  The
         # None default resolves against the *external* observers, so a

@@ -73,8 +73,8 @@ INFERENCE_ENGINES = ("numpy", "jax", "pallas")
 
 #: capacity-drain strategies: "host" is the chunked early-exit m-sweep
 #: (numpy rows shipped to the predictor once per chunk round), "device"
-#: the fused single-pass sweep (one padded scenario tensor, one
-#: ``rfr_sweep_op`` launch per drain of new scenarios)
+#: the fused single-pass sweep (one padded ``rfr_sweep_op`` launch per
+#: 128 new scenarios of a drain)
 DRAIN_MODES = ("host", "device")
 
 
@@ -444,10 +444,24 @@ class _Solve:
 _Query = Tuple[Coloc, str, int, Optional[NodeResources]]
 
 
+#: floors of the device drain's M and R buckets: scenarios that sweep
+#: few concurrencies or have few neighbours share one launch shape
+_M_FLOOR = 4
+_R_FLOOR = 8
+
+
 def _pow2(n: int, floor: int = 1) -> int:
     """Smallest power of two >= max(n, floor): the device drain pads its
     shapes to these buckets so that repeat drains reuse compiled code."""
     return max(floor, 1 << max(n - 1, 0).bit_length())
+
+
+def _buckets(n: int, floor: int) -> List[int]:
+    """Every bucket ``_pow2(k, floor)`` gives for k <= n."""
+    out = [floor]
+    while out[-1] < _pow2(n, floor):
+        out.append(2 * out[-1])
+    return out
 
 
 class PredictionService:
@@ -731,8 +745,8 @@ class PredictionService:
         Cache hits and duplicates resolve on the host either way;
         ``cfg.drain`` selects how the unique scenarios are solved: the
         chunked host m-sweep below, or the fused device sweep
-        (``_sweep_device``) — one padded scenario tensor, one kernel
-        pass, no per-chunk host round trips."""
+        (``_sweep_device``) — one kernel pass per 128 scenarios, no
+        per-chunk host round trips."""
         norm: List[_Query] = [q if len(q) == 4 else (*q, None)
                               for q in queries]
         self._check_epoch()
@@ -792,74 +806,110 @@ class PredictionService:
     # -- device drain (the fused Pallas/jnp m-sweep) -----------------------
 
     def _sweep_device(self, solves: List[_Solve]) -> None:
-        """Solve a drain's unique scenarios in one fused device pass: the
-        whole drain is ONE padded (S, M, R, F) tensor and the full
-        m-sweep runs in a single forest pass (``kernels.ops.rfr_sweep_op``)
-        that returns the max-admissible m per scenario — no host round
-        trip per chunk, host work O(unique signatures).  Each solve is
+        """Solve a drain's unique scenarios in fused device passes: each
+        padded (128, M, R, F) block of scenarios runs its full m-sweep in
+        a single forest pass (``kernels.ops.rfr_sweep_op``) that returns
+        the max-admissible m per scenario — no host round trip per
+        chunk, host work O(unique signatures).  Each solve is
         billed its full sweep (m_max x R rows).
 
         Row assembly stays in the float64 numpy ``_Template.build`` —
         device rows are the host oracle's rows — and the QoS bounds
         become tree-sum limits (``rfr_inference.sweep_limits``) with the
         host's own division and exp, so the capacities equal the host
-        drain's.  S (at least one 128-lane block), M and R are padded to
-        powers of two, so repeat drains reuse compiled code.
-        ``predictor.engine == "pallas"`` runs the fused Pallas kernel,
-        ``"jax"`` the jnp gather sweep; the numpy engine has no device
-        drain."""
+        drain's.  Each launch holds one 128-lane block of scenarios; M
+        and R are padded to power-of-two buckets (``_M_FLOOR``,
+        ``_R_FLOOR`` and up), so every launch takes one of the shapes
+        ``warm_device`` compiles.  A wider drain dispatches all of its
+        launches before it reads any back."""
         from ..kernels import ops
         from ..kernels.rfr_inference import LANES, sweep_limits
         import jax.numpy as jnp
 
-        engine = self.predictor.engine
-        if engine not in ("jax", "pallas"):
-            raise ValueError(
-                f"the device drain runs the jax or pallas engine, not "
-                f"{engine!r}; the numpy engine drains on the host")
+        use_pallas = self._device_uses_pallas()
         if not solves:
             return
         t0 = time.perf_counter()
         with self.tracer.span("device_sweep", stats=self.stats) as sp:
             F = self.schema.n_features
             S = len(solves)
-            Sp = _pow2(S, LANES)
-            Mp = _pow2(max(s.m_max for s in solves))
-            Rp = _pow2(max(s.tmpl.rows_per_m for s in solves))
+            Mp = _pow2(max(s.m_max for s in solves), _M_FLOOR)
+            Rp = _pow2(max(s.tmpl.rows_per_m for s in solves), _R_FLOOR)
             feat, thr, leaf = self.predictor.model.device_arrays()
             limits = sweep_limits(
                 np.concatenate([s.tmpl.bounds_per_m for s in solves]),
                 int(feat.shape[0]), self.predictor.log_target)
-            X = np.zeros((Sp, Mp, Rp, F), np.float32)
-            # +inf limit = padded row, passes; -inf = past this
-            # scenario's own m_max, fails (capacity capped there);
-            # padded scenarios pass everything and are never read
-            L = np.full((Sp, Mp, Rp), np.inf, np.float32)
+            launches = []
             off = 0
-            for j, s in enumerate(solves):
-                R, mm = s.tmpl.rows_per_m, max(s.m_max, 0)
-                if mm:
-                    rows, _bounds = s.tmpl.build(np.arange(1, mm + 1))
-                    X[j, :mm, :R, :] = rows.reshape(mm, R, F)
-                    L[j, :mm, :R] = limits[off:off + R]
-                L[j, mm:, :] = -np.inf
-                off += R
-                s.rows = mm * R
-            caps = np.asarray(ops.rfr_sweep_op(
-                jnp.asarray(X), jnp.asarray(L), feat, thr, leaf,
-                use_pallas=(engine == "pallas")))
+            for lo in range(0, S, LANES):
+                X = np.zeros((LANES, Mp, Rp, F), np.float32)
+                # +inf limit = padded row, passes; -inf = past this
+                # scenario's own m_max, fails (capacity capped there);
+                # padded scenarios pass everything and are never read
+                L = np.full((LANES, Mp, Rp), np.inf, np.float32)
+                for j, s in enumerate(solves[lo:lo + LANES]):
+                    R, mm = s.tmpl.rows_per_m, max(s.m_max, 0)
+                    if mm:
+                        rows, _bounds = s.tmpl.build(np.arange(1, mm + 1))
+                        X[j, :mm, :R, :] = rows.reshape(mm, R, F)
+                        L[j, :mm, :R] = limits[off:off + R]
+                    L[j, mm:, :] = -np.inf
+                    off += R
+                    s.rows = mm * R
+                launches.append(ops.rfr_sweep_op(
+                    jnp.asarray(X), jnp.asarray(L), feat, thr, leaf,
+                    use_pallas=use_pallas))
+            caps = np.concatenate([np.asarray(c) for c in launches])
             rows_built = sum(s.rows for s in solves)
             self.stats.rows_built += rows_built
             self.stats.predict_calls += 1
             if sp is not None:
                 sp.attrs["scenarios"] = S
                 sp.attrs["rows"] = rows_built
-                sp.attrs["padded_shape"] = [Sp, Mp, Rp, F]
+                sp.attrs["launches"] = len(launches)
+                sp.attrs["launch_shape"] = [LANES, Mp, Rp, F]
         for s, cap in zip(solves, caps):
             s.capacity = int(cap)
             s.done = True
         self.predictor.record_inference(rows_built,
                                         time.perf_counter() - t0)
+
+    def _device_uses_pallas(self) -> bool:
+        """The device drain's kernel: ``predictor.engine == "pallas"``
+        runs the fused Pallas sweep, ``"jax"`` the jnp gather sweep; the
+        numpy engine has no device drain."""
+        engine = self.predictor.engine
+        if engine not in ("jax", "pallas"):
+            raise ValueError(
+                f"the device drain runs the jax or pallas engine, not "
+                f"{engine!r}; the numpy engine drains on the host")
+        return engine == "pallas"
+
+    def warm_device(self) -> int:
+        """Compile every launch shape of the device drain before its
+        first drain: one 128-scenario block by each M bucket up to
+        ``cfg.m_max`` and each R bucket up to one row per function.  A
+        drain that compiled mid-run would stretch the scheduling
+        decision that asked for it, and a ``jiagu`` decision's wall time
+        delays its asynchronous table update, so a cold run would act
+        differently from a warm one.  Returns the number of shapes."""
+        from ..kernels import ops
+        from ..kernels.rfr_inference import LANES
+        import jax
+        import jax.numpy as jnp
+
+        use_pallas = self._device_uses_pallas()
+        feat, thr, leaf = self.predictor.model.device_arrays()
+        F = self.schema.n_features
+        shapes = [(m, r) for m in _buckets(self.cfg.m_max, _M_FLOOR)
+                  for r in _buckets(len(self.specs), _R_FLOOR)]
+        for Mp, Rp in shapes:
+            X = np.zeros((LANES, Mp, Rp, F), np.float32)
+            L = np.full((LANES, Mp, Rp), np.inf, np.float32)
+            jax.block_until_ready(ops.rfr_sweep_op(
+                jnp.asarray(X), jnp.asarray(L), feat, thr, leaf,
+                use_pallas=use_pallas))
+        return len(shapes)
 
     # -- node-level API (the async-update path) ---------------------------
 

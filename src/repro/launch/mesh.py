@@ -11,12 +11,13 @@ from __future__ import annotations
 import jax
 
 #: Published per-chip peaks keyed by ``jax.Device.device_kind``:
-#: bf16 FLOP/s, HBM bytes/s, and ICI bytes/s per link.  TPU v5e (Google
-#: Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM,
-#: 1,600 Gbit/s of interconnect per chip over 4 links.
+#: bf16 FLOP/s, HBM bytes/s, and ICI bytes/s per link.  TPU v5e, which
+#: reports itself as "TPU v5 lite" (Google Cloud documentation, "TPU
+#: v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect
+#: per chip over 4 links.
 PEAKS = {
-    kind: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 1600e9 / 8 / 4}
-    for kind in ("TPU v5 lite", "TPU v5e")
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "ici_bw": 1600e9 / 8 / 4},
 }
 #: the chip the production meshes below describe
 PRODUCTION_DEVICE_KIND = "TPU v5 lite"

@@ -23,7 +23,5 @@ def test_default_cache_dir_is_in_the_checkout(monkeypatch):
         path = compile_cache.enable_compile_cache()
         assert path == os.path.join(compile_cache.REPO_ROOT, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
-        assert os.path.isfile(os.path.join(compile_cache.REPO_ROOT,
-                                           "chip_smoke.py"))
     finally:
         jax.config.update("jax_compilation_cache_dir", before)

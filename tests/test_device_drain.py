@@ -98,7 +98,7 @@ def _three_way(world, schema):
         for k in STAT_KEYS:
             assert dev_stats[k] == ref_stats[k], \
                 f"{k}: device={dev_stats[k]} host={ref_stats[k]} ({engine})"
-        # warm drain: every signature resolves as a device-side gather
+        # warm drain: every signature resolves from the host cache
         rows_before = dev.stats.rows_built
         _clear(nodes)
         warm_rows = dev.update_nodes(nodes, m_max=m_max)
@@ -162,9 +162,39 @@ def test_device_drain_rows_billed_to_first_occurrence(world, restore_engine):
     assert c1 == c2
     assert r1 > 0 and r2 == 0          # dupe coalesced, billed once
     (c3, r3), = dev.solve_many([q])
-    assert c3 == c1 and r3 == 0        # warm: device gather, zero rows
+    assert c3 == c1 and r3 == 0        # warm: cache hit, zero rows
     assert dev.stats.coalesced_dupes == 1
     assert dev.stats.cache_hits == 1
+
+
+def test_device_drain_runs_only_warmed_launch_shapes(world, restore_engine):
+    """``warm_device`` compiles every launch shape a drain can take:
+    afterwards a drain wider than one 128-scenario launch, with mixed
+    m_max and neighbour counts, compiles nothing and equals the host
+    drain."""
+    from repro.kernels import ops
+    specs, gt, store, qos, pred = world
+    names = sorted(specs)
+    rng = np.random.default_rng(5)
+    queries = []
+    for _ in range(300):
+        coloc = {g: (float(rng.integers(1, 9)), float(rng.integers(0, 4)))
+                 for g in rng.choice(names, size=int(rng.integers(0, 5)),
+                                     replace=False)}
+        queries.append((coloc, names[int(rng.integers(len(names)))],
+                        int(rng.integers(1, 13)), None))
+    host = CapacityEngine(pred, store, qos, specs,
+                          EngineConfig(m_max=12, cache=False))
+    want = [c for c, _r in host.solve_many(queries)]
+    pred.engine = "jax"
+    dev = CapacityEngine(pred, store, qos, specs,
+                         EngineConfig(m_max=12, cache=False, drain="device"))
+    assert dev.warm_device() == 3      # M buckets 4, 8, 16 x R bucket 8
+    compiled = ops.rfr_sweep_op._cache_size()
+    got = [c for c, _r in dev.solve_many(queries)]
+    assert dev.stats.unique_solves > 128
+    assert got == want
+    assert ops.rfr_sweep_op._cache_size() == compiled
 
 
 def test_device_drain_empty_and_trivial_batches(world, restore_engine):

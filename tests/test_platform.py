@@ -145,13 +145,18 @@ def test_build_takes_the_backends_capacity_path(small_world, monkeypatch,
     """On a TPU the capacity solve drains on the chip with the compiled
     Pallas sweep unless the manifest names an engine, and ``numpy``
     always drains on the host; the CPU backend keeps the host numpy
-    path.  Only the build runs: the faked backend never compiles."""
+    path.  A device drain is warmed at build.  Only the build runs, and
+    the warm-up is recorded instead: the faked backend never compiles."""
     import jax
+    from repro.core.prediction_service import PredictionService
     _cfg, scenario, world = small_world
     prediction = dict(SMALL["prediction"])
     if engine is not None:
         prediction["engine"] = engine
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    warmed = []
+    monkeypatch.setattr(PredictionService, "warm_device",
+                        lambda self: warmed.append(self))
     prev = world.predictor.engine
     try:
         plat = Platform.build(scenario=scenario, world=world, config={
@@ -159,6 +164,7 @@ def test_build_takes_the_backends_capacity_path(small_world, monkeypatch,
             "prediction": prediction})
         assert (plat.service.inference_engine,
                 plat.service.cfg.drain) == path
+        assert warmed == ([plat.service] if path[1] == "device" else [])
     finally:
         world.predictor.engine = prev      # the world is module-shared
 

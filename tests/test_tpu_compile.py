@@ -59,7 +59,10 @@ def test_forest_apply_compiles_for_v5e(one_chip, T, F, N):
 
 
 @pytest.mark.parametrize("T,F,S,R", [(24, 31, 3, 6), (32, 33, 1024, 8),
-                                     (24, 33, 1025, 6)])
+                                     (24, 33, 1025, 6),
+                                     # the widest launch a 24-function
+                                     # platform warms (R bucket 32)
+                                     (24, 33, 128, 32)])
 def test_capacity_sweep_compiles_for_v5e(one_chip, T, F, S, R):
     M = 16
     x = jax.ShapeDtypeStruct((S, M, R, F), jnp.float32, sharding=one_chip)
