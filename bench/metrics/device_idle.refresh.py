@@ -1,0 +1,2 @@
+"""Per-layer metric ``device_idle.refresh``: see ``bench/layers.py:device_idle``."""
+from bench.layers import device_idle as read  # noqa: F401

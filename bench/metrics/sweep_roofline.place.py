@@ -1,0 +1,2 @@
+"""Per-layer metric ``sweep_roofline.place``: see ``bench/layers.py:sweep_roofline``."""
+from bench.layers import sweep_roofline as read  # noqa: F401
