@@ -23,6 +23,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, Iterable, List, Optional, Tuple
 
+from ..telemetry.spans import NULL_TRACER
 from .cluster import Cluster, Node
 from .events import EventHub
 from .metrics import Reservoir
@@ -170,6 +171,10 @@ class Autoscaler:
         #: queue/SLO context onto DecisionTraces; None (default) keeps
         #: every pre-admission code path untouched.
         self.admission = None
+        #: span tracer for the autoscaler's phases (``autoscale``,
+        #: ``migrate``, ``reap``, ``place``); ``Platform.build`` swaps in
+        #: a real one when spans are on
+        self.tracer = NULL_TRACER
         self._below_since: Dict[str, Optional[float]] = {}
         self._ledger = _CachedLedger()
         #: event-core hook — called with fn when an out-of-band mutation
@@ -216,16 +221,29 @@ class Autoscaler:
         functions, already ordered like ``cluster.specs`` — skipped
         functions are exactly those whose ``_tick_fn`` would have been a
         no-op (no load, no timers armed, no ledger entries)."""
-        for fn in (self.cluster.specs if fns is None else fns):
-            self._tick_fn(now, fn, rps.get(fn, 0.0))
-        if self.cfg.dual_staged and self.cfg.migrate:
-            self._migrate(now)
-        if self.admission is not None:
-            # vertical resize rides the horizontal pass: shrink/grow
-            # cpu reservations, re-solved against the capacity table
-            self.admission.vertical_tick(now, self.cluster,
-                                         self.scheduler, self.events)
-        self.cluster.reap_empty()
+        with self.tracer.phase("autoscale") as sp:
+            m = self.metrics
+            if sp is not None:
+                r0, l0, e0 = m.releases, m.logical_cold_starts, m.evictions
+            n_fns = 0
+            for fn in (self.cluster.specs if fns is None else fns):
+                self._tick_fn(now, fn, rps.get(fn, 0.0))
+                n_fns += 1
+            if self.cfg.dual_staged and self.cfg.migrate:
+                self._migrate(now)
+            if self.admission is not None:
+                # vertical resize rides the horizontal pass: shrink/grow
+                # cpu reservations, re-solved against the capacity table
+                self.admission.vertical_tick(now, self.cluster,
+                                             self.scheduler, self.events)
+            with self.tracer.phase("reap") as rsp:
+                reaped = self.cluster.reap_empty()
+                if rsp is not None:
+                    rsp.attrs["reaped"] = reaped
+            if sp is not None:
+                sp.attrs.update(fns=n_fns, released=m.releases - r0,
+                                logical_starts=m.logical_cold_starts - l0,
+                                evicted=m.evictions - e0)
 
     def next_wake(self, fn: str) -> Optional[float]:
         """Earliest future time fn needs autoscaler attention absent any
@@ -266,8 +284,22 @@ class Autoscaler:
                 self.metrics.blocked_logical += min(
                     need, self.cluster.cached_count(fn))
         if need > 0:
-            placements = self.scheduler.schedule(fn, need, now)
-            placed = sum(p.count for p in placements)
+            with self.tracer.phase("place") as sp:
+                if sp is not None:
+                    sm = self.scheduler.metrics
+                    svc = self.scheduler.prediction_service
+                    f0, s0 = sm.fast, sm.slow
+                    t0 = sm.critical_inference_calls
+                    c0 = svc.stats.predict_calls if svc is not None else 0
+                placements = self.scheduler.schedule(fn, need, now)
+                placed = sum(p.count for p in placements)
+                if sp is not None:
+                    sp.attrs.update(
+                        fn=fn, count=need, placed=placed,
+                        fast=sm.fast - f0, slow=sm.slow - s0,
+                        drains=svc.stats.predict_calls - c0
+                        if svc is not None else 0,
+                        nodes_tried=sm.critical_inference_calls - t0)
             self.metrics.real_cold_starts += placed
             for p in placements:
                 self.metrics.cold_start_ms.extend(
@@ -369,37 +401,49 @@ class Autoscaler:
         was already in the snapshot or keeps ``n_sat > 0`` with
         post-move excess <= 0 (the target-fit condition), so the full
         scan would not have acted on it either."""
-        for node in self.cluster.nodes_with_cached():
-            all_cached = all(s.n_sat == 0 for s in node.funcs.values()) \
-                and node.n_instances() > 0
-            for fn, st in list(node.funcs.items()):
-                if st.n_cached == 0:
-                    continue
-                cap = self._node_capacity(node, fn)
-                if all_cached:
-                    k = st.n_cached
-                elif cap is not None:
-                    excess = st.n_sat + st.n_cached - cap
-                    if excess <= 0:
+        with self.tracer.phase("migrate") as sp:
+            sources = self.cluster.nodes_with_cached()
+            moved0 = self.metrics.migrations
+            scans = 0
+            for node in sources:
+                all_cached = all(s.n_sat == 0 for s in node.funcs.values()) \
+                    and node.n_instances() > 0
+                for fn, st in list(node.funcs.items()):
+                    if st.n_cached == 0:
                         continue
-                    k = min(excess, st.n_cached)
-                else:
-                    continue
-                target = self._find_migration_target(fn, node, k)
-                if target is None:
-                    continue
-                node.evict_cached(fn, k)
-                target.add_cached(fn, k)
-                self._ledger.move(fn, node.id, target.id, k)
-                self.metrics.migrations += k
-                self.scheduler.notify_change(node, now)
-                self.scheduler.notify_change(target, now)
-                self.events.on_scale(now, fn, "migrate", k)
+                    cap = self._node_capacity(node, fn)
+                    if all_cached:
+                        k = st.n_cached
+                    elif cap is not None:
+                        excess = st.n_sat + st.n_cached - cap
+                        if excess <= 0:
+                            continue
+                        k = min(excess, st.n_cached)
+                    else:
+                        continue
+                    target, scanned = self._find_migration_target(fn, node, k)
+                    scans += scanned
+                    if target is None:
+                        continue
+                    node.evict_cached(fn, k)
+                    target.add_cached(fn, k)
+                    self._ledger.move(fn, node.id, target.id, k)
+                    self.metrics.migrations += k
+                    self.scheduler.notify_change(node, now)
+                    self.scheduler.notify_change(target, now)
+                    self.events.on_scale(now, fn, "migrate", k)
+            if sp is not None:
+                sp.attrs.update(nodes_scanned=len(sources),
+                                target_scans=scans,
+                                moved=self.metrics.migrations - moved0)
 
     def _find_migration_target(self, fn: str, src: Node, k: int
-                               ) -> Optional[Node]:
-        for node in sorted(self.cluster.nodes_with(fn),
-                           key=lambda n: -n.funcs[fn].n_sat):
+                               ) -> Tuple[Optional[Node], int]:
+        """The busiest node hosting fn with room for k more, and the
+        number of candidates sorted to find it."""
+        cands = sorted(self.cluster.nodes_with(fn),
+                       key=lambda n: -n.funcs[fn].n_sat)
+        for node in cands:
             if node.id == src.id:
                 continue
             cap = self._node_capacity(node, fn)
@@ -408,5 +452,5 @@ class Autoscaler:
             st = node.funcs[fn]
             if (cap - st.n_sat - st.n_cached >= k
                     and self.cluster.mem_headroom(node, fn) >= k):
-                return node
-        return None
+                return node, len(cands)
+        return None, len(cands)

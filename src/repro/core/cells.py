@@ -403,14 +403,17 @@ class CellSimulation:
             cell_rps = adm.drain(now, cell.cluster, res)
         if not cell.prev_active and not cell.scheduler.needs_idle_observe:
             return      # no live traffic: nothing measurable, no-op observes
-        sat_totals = {fn: cell.cluster.sat_count(fn)
-                      for fn in cell.prev_active} \
-            if not cell.scheduler.needs_idle_observe \
-            else {fn: cell.cluster.sat_count(fn) for fn in self.specs}
-        measure_cluster(now, cell.cluster, self.specs, cell_rps,
-                        sat_totals, cell.router, cell.scheduler,
-                        self.gt, self.qos, res,
-                        slo=None if adm is None else adm.slo)
+        with self.tracer.phase("measure") as sp:
+            sat_totals = {fn: cell.cluster.sat_count(fn)
+                          for fn in cell.prev_active} \
+                if not cell.scheduler.needs_idle_observe \
+                else {fn: cell.cluster.sat_count(fn) for fn in self.specs}
+            measure_cluster(now, cell.cluster, self.specs, cell_rps,
+                            sat_totals, cell.router, cell.scheduler,
+                            self.gt, self.qos, res,
+                            slo=None if adm is None else adm.slo)
+            if sp is not None:
+                sp.attrs["nodes"] = len(cell.cluster.nodes)
 
     def _collect_sample(self) -> None:
         """Mirror of ``Simulation._collect_sample`` over the fleet:

@@ -22,9 +22,10 @@ without the run loop knowing who is listening:
     at the end of ``Simulation.run`` / ``CellSimulation.run`` so JSONL
     artifacts carry their own outcome record,
   * ``on_span(span)``            — a control-plane span closed
-    (``repro.telemetry.spans``): wall-clock + counter deltas for
-    ``schedule`` / ``retrain`` / ``capacity_solve`` sections, persisted
-    alongside the ``DecisionTrace`` stream.
+    (``repro.telemetry.spans``): wall-clock and counters for
+    ``schedule`` / ``retrain`` / ``capacity_solve`` sections and the
+    phases inside them, persisted alongside the ``DecisionTrace``
+    stream.
 
 ``EventHub`` fans one event out to every registered observer; the hub
 with no observers is the default everywhere and costs one empty-list

@@ -832,8 +832,11 @@ class Platform:
                 hub.add(telemetry.observer)
             if want_spans:
                 simulation.tracer = telemetry.tracer
-                for service in services:
-                    service.tracer = telemetry.tracer
+                autoscalers = [c.autoscaler for c in simulation.cells] \
+                    if isinstance(simulation, CellSimulation) \
+                    else [simulation.autoscaler]
+                for part in autoscalers + services:
+                    part.tracer = telemetry.tracer
         # pipeline section: trace toggle + named picker-stage overrides
         # (applied to every cell's scheduler on the sharded path)
         scheds = simulation.schedulers() \

@@ -754,23 +754,30 @@ class PredictionService:
         results: List[Optional[Tuple[int, int]]] = [None] * len(norm)
         unique: Dict[SigKey, _Solve] = {}
         assignment: List[Optional[SigKey]] = [None] * len(norm)
-        for i, (coloc, fn, m_max, node_res) in enumerate(norm):
-            key = self.signature(coloc, fn, m_max, node_res)
-            if self.cfg.cache:
-                cap = self._cache_get(key)
-                if cap is not None:
-                    results[i] = (cap, 0)
-                    self.stats.cache_hits += 1
-                    continue
-            if key in unique:
-                self.stats.coalesced_dupes += 1
-            else:
-                unique[key] = _Solve(
-                    _Template(self.store, self.qos, self.specs, coloc, fn,
-                              self.schema, node_res,
-                              self.qos_bound_scale(node_res)), m_max)
-                self.stats.unique_solves += 1
-            assignment[i] = key
+        with self.tracer.phase("solve.lookup") as sp:
+            hits0, dupes0 = self.stats.cache_hits, self.stats.coalesced_dupes
+            for i, (coloc, fn, m_max, node_res) in enumerate(norm):
+                key = self.signature(coloc, fn, m_max, node_res)
+                if self.cfg.cache:
+                    cap = self._cache_get(key)
+                    if cap is not None:
+                        results[i] = (cap, 0)
+                        self.stats.cache_hits += 1
+                        continue
+                if key in unique:
+                    self.stats.coalesced_dupes += 1
+                else:
+                    unique[key] = _Solve(
+                        _Template(self.store, self.qos, self.specs, coloc,
+                                  fn, self.schema, node_res,
+                                  self.qos_bound_scale(node_res)), m_max)
+                    self.stats.unique_solves += 1
+                assignment[i] = key
+            if sp is not None:
+                sp.attrs.update(
+                    queries=len(norm), unique=len(unique),
+                    cache_hits=self.stats.cache_hits - hits0,
+                    dupes=self.stats.coalesced_dupes - dupes0)
 
         if self.cfg.drain == "device":
             self._sweep_device(list(unique.values()))
@@ -820,8 +827,10 @@ class PredictionService:
         drain's.  Each launch holds one 128-lane block of scenarios; M
         and R are padded to power-of-two buckets (``_M_FLOOR``,
         ``_R_FLOOR`` and up), so every launch takes one of the shapes
-        ``warm_device`` compiles.  A wider drain dispatches all of its
-        launches before it reads any back."""
+        ``warm_device`` compiles.  A wider drain assembles all of its
+        blocks, then dispatches all of its launches, then reads them back:
+        the three phases (``drain.assemble``, ``drain.launch``,
+        ``drain.readback``) are spans of their own."""
         from ..kernels import ops
         from ..kernels.rfr_inference import LANES, sweep_limits
         import jax.numpy as jnp
@@ -830,37 +839,47 @@ class PredictionService:
         if not solves:
             return
         t0 = time.perf_counter()
-        with self.tracer.span("device_sweep", stats=self.stats) as sp:
+        with self.tracer.span("device_sweep") as sp:
             F = self.schema.n_features
             S = len(solves)
             Mp = _pow2(max(s.m_max for s in solves), _M_FLOOR)
             Rp = _pow2(max(s.tmpl.rows_per_m for s in solves), _R_FLOOR)
             feat, thr, leaf = self.predictor.model.device_arrays()
-            limits = sweep_limits(
-                np.concatenate([s.tmpl.bounds_per_m for s in solves]),
-                int(feat.shape[0]), self.predictor.log_target)
-            launches = []
-            off = 0
-            for lo in range(0, S, LANES):
-                X = np.zeros((LANES, Mp, Rp, F), np.float32)
-                # +inf limit = padded row, passes; -inf = past this
-                # scenario's own m_max, fails (capacity capped there);
-                # padded scenarios pass everything and are never read
-                L = np.full((LANES, Mp, Rp), np.inf, np.float32)
-                for j, s in enumerate(solves[lo:lo + LANES]):
-                    R, mm = s.tmpl.rows_per_m, max(s.m_max, 0)
-                    if mm:
-                        rows, _bounds = s.tmpl.build(np.arange(1, mm + 1))
-                        X[j, :mm, :R, :] = rows.reshape(mm, R, F)
-                        L[j, :mm, :R] = limits[off:off + R]
-                    L[j, mm:, :] = -np.inf
-                    off += R
-                    s.rows = mm * R
-                launches.append(ops.rfr_sweep_op(
+            with self.tracer.phase("drain.assemble") as ph:
+                limits = sweep_limits(
+                    np.concatenate([s.tmpl.bounds_per_m for s in solves]),
+                    int(feat.shape[0]), self.predictor.log_target)
+                blocks = []
+                off = 0
+                for lo in range(0, S, LANES):
+                    X = np.zeros((LANES, Mp, Rp, F), np.float32)
+                    # +inf limit = padded row, passes; -inf = past this
+                    # scenario's own m_max, fails (capacity capped
+                    # there); padded scenarios pass everything and are
+                    # never read
+                    L = np.full((LANES, Mp, Rp), np.inf, np.float32)
+                    for j, s in enumerate(solves[lo:lo + LANES]):
+                        R, mm = s.tmpl.rows_per_m, max(s.m_max, 0)
+                        if mm:
+                            rows, _bounds = s.tmpl.build(
+                                np.arange(1, mm + 1))
+                            X[j, :mm, :R, :] = rows.reshape(mm, R, F)
+                            L[j, :mm, :R] = limits[off:off + R]
+                        L[j, mm:, :] = -np.inf
+                        off += R
+                        s.rows = mm * R
+                    blocks.append((X, L))
+                rows_built = sum(s.rows for s in solves)
+                if ph is not None:
+                    ph.attrs["rows"] = rows_built
+            with self.tracer.phase("drain.launch") as ph:
+                launches = [ops.rfr_sweep_op(
                     jnp.asarray(X), jnp.asarray(L), feat, thr, leaf,
-                    use_pallas=use_pallas))
-            caps = np.concatenate([np.asarray(c) for c in launches])
-            rows_built = sum(s.rows for s in solves)
+                    use_pallas=use_pallas) for X, L in blocks]
+                if ph is not None:
+                    ph.attrs["launches"] = len(launches)
+            with self.tracer.phase("drain.readback"):
+                caps = np.concatenate([np.asarray(c) for c in launches])
             self.stats.rows_built += rows_built
             self.stats.predict_calls += 1
             if sp is not None:
@@ -925,7 +944,7 @@ class PredictionService:
         """Recompute every capacity-table entry of every node in one
         coalesced drain (node-shape-aware under schema v2).  Returns
         total inference rows billed."""
-        with self.tracer.span("capacity_solve", stats=self.stats) as sp:
+        with self.tracer.span("capacity_solve") as sp:
             mm = m_max or self.cfg.m_max
             queries: List[_Query] = []
             owners: List[Tuple[Node, str]] = []
@@ -975,7 +994,7 @@ class PredictionService:
         lookup can see a pre-retrain capacity.  Wall time is billed to
         ``stats.retrain_time_s`` (background work, never the scheduling
         critical path)."""
-        with self.tracer.span("retrain", stats=self.stats) as sp:
+        with self.tracer.span("retrain") as sp:
             t0 = time.perf_counter()
             self.predictor.retrain()
             self._check_epoch()     # epoch bump -> invalidate()

@@ -37,7 +37,11 @@ class SchedMetrics:
     slow: int = 0
     failed: int = 0
     sched_time_ms: float = 0.0
-    # bounded: 512-node full-trace runs record one sample per decision
+    #: the modelled decision latency: ``FAST_PATH_MS`` for each
+    #: fast-path placement plus the measured slow-path solves, sampled
+    #: to 512 decisions (``Reservoir``: 512-node full-trace runs record
+    #: one sample per decision).  The ``place`` span (``Autoscaler``)
+    #: is the measured one: the wall time of the whole ``schedule`` call
     sched_latencies: Reservoir = field(
         default_factory=lambda: Reservoir(512))
     critical_inference_rows: int = 0

@@ -239,9 +239,9 @@ class Simulation:
         #: None (the default) keeps the run loop structurally identical
         #: to the pre-admission control plane.
         self.admission = None
-        #: span tracer for the per-tick scheduling section; the no-op
-        #: default keeps uninstrumented runs on the identical code path
-        #: (spans only read state — see the observer-parity test)
+        #: span tracer for the per-tick scheduling and measurement; the
+        #: no-op default keeps uninstrumented runs on the identical code
+        #: path (spans only read state — see the observer-parity test)
         self.tracer = NULL_TRACER
         self.cluster = scheduler.cluster
         self._rng = np.random.default_rng(self.cfg.seed)
@@ -364,13 +364,17 @@ class Simulation:
     # ------------------------------------------------------------------
 
     def _measure(self, now: float, rps: Dict[str, float], res: SimResult):
-        # O(1) reads off the cluster's incremental per-function totals
-        sat_totals = {fn: self.cluster.sat_count(fn) for fn in self.specs}
-        measure_cluster(now, self.cluster, self.specs, rps, sat_totals,
-                        self.router, self.scheduler, self.gt, self.qos,
-                        res,
-                        slo=None if self.admission is None
-                        else self.admission.slo)
+        with self.tracer.phase("measure") as sp:
+            # O(1) reads off the cluster's incremental per-function totals
+            sat_totals = {fn: self.cluster.sat_count(fn)
+                          for fn in self.specs}
+            measure_cluster(now, self.cluster, self.specs, rps, sat_totals,
+                            self.router, self.scheduler, self.gt, self.qos,
+                            res,
+                            slo=None if self.admission is None
+                            else self.admission.slo)
+            if sp is not None:
+                sp.attrs["nodes"] = len(self.cluster.nodes)
 
     def _collect_sample(self):
         """Runtime training-sample collection (training nodes, §3/§6):

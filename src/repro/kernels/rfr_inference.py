@@ -171,6 +171,7 @@ def rfr_forest_apply(x, feat, thr, leaf, *, block_n: int = 1024,
         out_specs=pl.BlockSpec((rb, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cols_p, LANES), jnp.float32),
         interpret=interpret,
+        name="rfr_forest_apply",
     )(xt, feat, thr, leaf)
     return out.reshape(-1)[:N]
 
@@ -229,6 +230,9 @@ def rfr_capacity_sweep(x, limits, feat, thr, leaf, *, block_s: int = LANES,
         out_shape=jax.ShapeDtypeStruct((1, Sp), jnp.int32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
+        # the kernel's op in a device trace is named after this, not
+        # after the jitted function that calls it
+        name="rfr_sweep_op",
     )(xt, lt, feat, thr, leaf)
     return out[0, :S]
 

@@ -6,10 +6,12 @@ One subsystem, four layers:
   (``Counter`` / ``Gauge`` / ``Histogram`` on ``core.metrics.Reservoir``)
   in a ``MetricsRegistry``, fed by ``MetricsObserver`` through the
   ``EventHub`` and by ``publish_result`` at end-of-run;
-* :mod:`~repro.telemetry.spans` — span-based control-plane tracing
-  (``span("schedule")``, ``span("retrain")``, ``span("capacity_solve")``)
-  with wall-clock + counter deltas, emitted through ``on_span`` into the
-  same JSONL streams as ``DecisionTrace``; ``NULL_TRACER`` keeps
+* :mod:`~repro.telemetry.spans` — span-based control-plane tracing:
+  layer spans (``span("schedule")``, ``span("capacity_solve")``, ...)
+  and the phase spans inside them (``phase("migrate")``, ...) with
+  wall-clock and the counters each site sets, emitted through
+  ``on_span`` into the same JSONL streams as ``DecisionTrace`` and
+  opened as profiler annotations; ``NULL_TRACER`` keeps
   uninstrumented runs free;
 * :mod:`~repro.telemetry.report` — the schema-versioned ``RunReport``
   persisted as a ``BENCH_<study>.json`` trajectory (baseline + runs);

@@ -1,56 +1,74 @@
 """Span-based control-plane tracing.
 
-The simulator and the prediction service wrap their interesting
-sections in spans::
+The simulator, the autoscaler and the prediction service wrap their
+interesting sections in spans::
 
     with tracer.span("schedule", now=now) as sp:
         ...
         sp.attrs["decisions"] = placed
 
-A closed span records wall-clock duration, nesting depth, a sequence
-number, and arbitrary attributes (counter deltas, sim time).  Spans are
-emitted through the observer hub's ``on_span`` hook as they close, so
-``JsonlObserver`` persists them into the same JSONL stream as the
-``DecisionTrace`` records — one artifact per run tells the whole story.
+A closed span records wall-clock duration, a sequence number, the
+sequence number of the span it opened inside (``parent``; None at the
+top), and arbitrary attributes (counters the span site sets itself,
+sim time).  Spans are emitted through the observer hub's ``on_span``
+hook as they close, so ``JsonlObserver`` persists them into the same
+JSONL stream as the ``DecisionTrace`` records — one artifact per run
+tells the whole story.
 
-``NULL_TRACER`` is the default everywhere: its ``span()`` is a shared
-no-op context manager whose ``__enter__`` returns ``None``, so
-uninstrumented runs pay two attribute lookups per span site and
-allocate nothing (the observer-parity gates run with and without a real
-tracer and must agree bit-for-bit — spans only *read* state).
+Two kinds of span:
 
-Counter deltas: ``tracer.span(name, stats=obj)`` snapshots
-``obj.snapshot()`` (any mapping-returning callable, e.g.
-``PredictionService.stats``) on entry and records the numeric deltas on
-exit — the "wall-clock + counter deltas" contract without span sites
-hand-rolling bookkeeping.
+* **layer spans** (``tracer.span``): ``schedule``, ``admission``,
+  ``capacity_solve``, ``device_sweep``, ``retrain`` — one per layer
+  entered.  Their ``depth`` counts the layer spans open around them.
+* **phase spans** (``tracer.phase``): the parts of a layer's work
+  (``autoscale``, ``migrate``, ``reap``, ``place``, ``measure``,
+  ``solve.lookup``, ``drain.assemble``, ``drain.launch``,
+  ``drain.readback``).  They record no depth (None), so a reader that
+  finds a layer's children by depth never counts them; ``parent`` says
+  where they ran.
+
+A ``SpanTracer`` also opens a ``jax.profiler.TraceAnnotation`` named
+``ANNOTATION_PREFIX + name`` around every span, so that in a profiled
+run the program's spans sit on the profiler's host plane, on the same
+clock as the device's operations.
+
+``NULL_TRACER`` is the default everywhere: its ``span()`` and
+``phase()`` return one shared no-op context manager whose ``__enter__``
+returns ``None``, so uninstrumented runs pay two attribute lookups per
+span site, allocate nothing and never touch the profiler (the
+observer-parity gates run with and without a real tracer and must
+agree bit-for-bit — spans only *read* state).
 """
 from __future__ import annotations
 
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+#: prefix of the profiler annotation each span opens (``cp.schedule``,
+#: ``cp.drain.readback``)
+ANNOTATION_PREFIX = "cp."
+
 
 class Span:
     """One closed (or in-flight) control-plane section."""
 
-    __slots__ = ("name", "seq", "depth", "t_start_s", "dur_ms", "attrs",
-                 "_stats", "_snap0")
+    __slots__ = ("name", "seq", "depth", "parent", "t_start_s", "dur_ms",
+                 "attrs")
 
-    def __init__(self, name: str, seq: int, depth: int,
-                 stats: Optional[Any] = None, **attrs: Any):
+    def __init__(self, name: str, seq: int, depth: Optional[int],
+                 parent: Optional[int] = None, **attrs: Any):
         self.name = name
         self.seq = seq
         self.depth = depth
+        self.parent = parent
         self.t_start_s = 0.0
         self.dur_ms = 0.0
         self.attrs: Dict[str, Any] = dict(attrs)
-        self._stats = stats
-        self._snap0: Optional[Dict[str, float]] = None
 
     def to_dict(self) -> Dict[str, Any]:
         return {"name": self.name, "seq": self.seq, "depth": self.depth,
-                "ms": round(self.dur_ms, 4), **self.attrs}
+                "parent": self.parent, "ms": round(self.dur_ms, 4),
+                **self.attrs}
 
     def __repr__(self) -> str:
         return (f"Span({self.name!r}, seq={self.seq}, "
@@ -77,8 +95,10 @@ class _NullTracer:
 
     enabled = False
 
-    def span(self, name: str, stats: Optional[Any] = None,
-             **attrs: Any) -> _NullSpanCM:
+    def span(self, name: str, **attrs: Any) -> _NullSpanCM:
+        return _NULL_CM
+
+    def phase(self, name: str, **attrs: Any) -> _NullSpanCM:
         return _NULL_CM
 
     def summary(self) -> List[Dict[str, Any]]:
@@ -89,32 +109,34 @@ NULL_TRACER = _NullTracer()
 
 
 class _SpanCM:
-    __slots__ = ("tracer", "sp")
+    __slots__ = ("tracer", "sp", "ann")
 
     def __init__(self, tracer: "SpanTracer", sp: Span):
         self.tracer = tracer
         self.sp = sp
+        self.ann = None
 
     def __enter__(self) -> Span:
-        self.tracer._depth += 1
-        self.sp.t_start_s = time.perf_counter()
-        if self.sp._stats is not None:
-            self.sp._snap0 = dict(self.sp._stats.snapshot())
-        return self.sp
+        tr, sp = self.tracer, self.sp
+        if tr._open:
+            sp.parent = tr._open[-1].seq
+        if sp.depth is not None:
+            sp.depth = tr._depth
+            tr._depth += 1
+        tr._open.append(sp)
+        self.ann = tr._annotation(ANNOTATION_PREFIX + sp.name)
+        self.ann.__enter__()
+        sp.t_start_s = time.perf_counter()
+        return sp
 
     def __exit__(self, *exc) -> bool:
-        sp = self.sp
+        tr, sp = self.tracer, self.sp
         sp.dur_ms = (time.perf_counter() - sp.t_start_s) * 1e3
-        if sp._snap0 is not None:
-            snap1 = self.sp._stats.snapshot()
-            for k, v1 in snap1.items():
-                d = v1 - sp._snap0.get(k, 0)
-                if isinstance(d, float):
-                    d = round(d, 6)
-                if d:
-                    sp.attrs[f"d_{k}"] = d
-        self.tracer._depth -= 1
-        self.tracer._finish(sp)
+        self.ann.__exit__(None, None, None)
+        tr._open.pop()
+        if sp.depth is not None:
+            tr._depth -= 1
+        tr._finish(sp)
         return False
 
 
@@ -127,16 +149,26 @@ class SpanTracer:
 
     def __init__(self, emit: Optional[Callable[[Span], None]] = None,
                  max_spans: int = 100_000):
+        import jax.profiler
+
         self.spans: List[Span] = []
         self.dropped = 0
         self.max_spans = max_spans
         self._emit = emit
         self._depth = 0
         self._seq = 0
+        self._open: List[Span] = []
+        self._annotation = jax.profiler.TraceAnnotation
 
-    def span(self, name: str, stats: Optional[Any] = None,
-             **attrs: Any) -> _SpanCM:
-        sp = Span(name, self._seq, self._depth, stats=stats, **attrs)
+    def span(self, name: str, **attrs: Any) -> _SpanCM:
+        """A layer span: its depth counts the layer spans around it."""
+        sp = Span(name, self._seq, 0, **attrs)
+        self._seq += 1
+        return _SpanCM(self, sp)
+
+    def phase(self, name: str, **attrs: Any) -> _SpanCM:
+        """A phase span inside a layer: no depth, only its parent."""
+        sp = Span(name, self._seq, None, **attrs)
         self._seq += 1
         return _SpanCM(self, sp)
 

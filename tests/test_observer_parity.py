@@ -99,6 +99,11 @@ def test_bare_hub_and_jsonl_runs_are_bit_identical(tmp_path):
               for l in (tmp_path / "events.jsonl").read_text().splitlines()]
     kinds = {e["event"] for e in events}
     assert {"meta", "tick", "schedule", "scale", "span"} <= kinds
+    # the autoscaler's and the loop's phase spans were on in every
+    # instrumented arm
+    spans = {e["name"] for e in events if e["event"] == "span"}
+    assert {"schedule", "autoscale", "migrate", "reap", "place",
+            "measure", "solve.lookup"} <= spans
 
 
 def test_telemetry_registry_agrees_with_sim_counters():

@@ -73,9 +73,9 @@ def test_counter_snapshot_integral_values_stay_ints():
 
 
 def test_null_tracer_is_free_and_shared():
-    cm1 = NULL_TRACER.span("anything", stats=object(), junk=1)
+    cm1 = NULL_TRACER.span("anything", junk=1)
     cm2 = NULL_TRACER.span("other")
-    assert cm1 is cm2                 # one shared no-op CM
+    assert cm1 is cm2 is NULL_TRACER.phase("inner")   # one shared no-op CM
     with cm1 as sp:
         assert sp is None
     assert NULL_TRACER.summary() == []
@@ -99,21 +99,30 @@ def test_span_tracer_records_emits_and_aggregates():
     json.dumps(d)
 
 
-def test_span_counter_deltas_from_stats_snapshot():
-    class Stats:
-        def __init__(self):
-            self.calls = 0
+def test_span_counters_are_the_ones_the_site_sets():
+    """A span carries the counters its site sets, and nothing copied
+    from the service's stats."""
+    from repro.core.prediction_service import PredictionService
+    from repro.core.scenarios import make_scenario, scenario_world
 
-        def snapshot(self):
-            return {"calls": self.calls, "still": 1.0}
-
-    st = Stats()
-    tr = SpanTracer()
-    with tr.span("work", stats=st):
-        st.calls += 5
-    sp = tr.spans[0]
-    assert sp.attrs["d_calls"] == 5
-    assert "d_still" not in sp.attrs  # zero deltas elided
+    scn = make_scenario("burst-storm", n_functions=4, duration_s=10,
+                        target_nodes=4, seed=0)
+    w = scenario_world(scn, n_train=200, n_trees=4, max_depth=4)
+    svc = PredictionService(w.predictor, w.store, w.qos, scn.specs)
+    svc.tracer = SpanTracer()
+    names = sorted(scn.specs)
+    node = {names[1]: (2.0, 0.0), names[0]: (1.0, 0.0)}
+    svc.solve_many([(node, names[0], 8), (node, names[0], 8),
+                    (node, names[1], 8)])
+    svc.solve_many([(node, names[0], 8)])
+    first, second = [s for s in svc.tracer.spans
+                     if s.name == "solve.lookup"]
+    assert first.attrs == {"queries": 3, "unique": 2, "cache_hits": 0,
+                           "dupes": 1}
+    assert second.attrs == {"queries": 1, "unique": 0, "cache_hits": 1,
+                            "dupes": 0}
+    assert not any(k.startswith("d_") for s in svc.tracer.spans
+                   for k in s.attrs)
 
 
 def test_span_tracer_bounded():

@@ -8,6 +8,7 @@ topology is described inside a fixture, never at import: only the worker
 that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,3 +72,20 @@ def test_capacity_sweep_compiles_for_v5e(one_chip, T, F, S, R):
         lambda *a: rfr_capacity_sweep(*a, interpret=False)).lower(
         x, lim, *_forest(one_chip, T)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sweep_kernel_op_keeps_its_name(one_chip):
+    """The sweep's op in a device trace is named by the kernel
+    (``%rfr_sweep_op.<n>``), whatever jitted function calls it: the
+    name the benchmark's kernel-time reading looks for."""
+    T, F, S, M, R = 24, 31, 128, 32, 8
+    x = jax.ShapeDtypeStruct((S, M, R, F), jnp.float32, sharding=one_chip)
+    lim = jax.ShapeDtypeStruct((S, M, R), jnp.float32, sharding=one_chip)
+
+    def any_caller(*a):
+        return rfr_capacity_sweep(*a, interpret=False)
+
+    text = jax.jit(any_caller).lower(x, lim, *_forest(one_chip, T)) \
+        .compile().as_text()
+    ops = re.findall(r"(%[\w.\-]+) = [^\n]*tpu_custom_call", text)
+    assert ops and all(op.startswith("%rfr_sweep_op") for op in ops)
