@@ -379,12 +379,6 @@ class Autoscaler:
 
     # -- on-demand migration (paper §5) ---------------------------------
 
-    def _node_capacity(self, node: Node, fn: str) -> Optional[int]:
-        """Best known capacity of fn on node, via the pluggable
-        ``CapacityProvider`` (default: capacity table, then zero-cost
-        service cache hints)."""
-        return self.capacity.node_capacity(node, fn)
-
     def _migrate(self, now: float):
         """Move cached instances off nodes where they could no longer be
         re-saturated (n_sat + n_cached > capacity), hiding the real cold
@@ -403,15 +397,15 @@ class Autoscaler:
         scan would not have acted on it either."""
         with self.tracer.phase("migrate") as sp:
             sources = self.cluster.nodes_with_cached()
+            targets = _TargetIndex(self.cluster, self.capacity)
             moved0 = self.metrics.migrations
-            scans = 0
             for node in sources:
                 all_cached = all(s.n_sat == 0 for s in node.funcs.values()) \
                     and node.n_instances() > 0
                 for fn, st in list(node.funcs.items()):
                     if st.n_cached == 0:
                         continue
-                    cap = self._node_capacity(node, fn)
+                    cap = self.capacity.node_capacity(node, fn)
                     if all_cached:
                         k = st.n_cached
                     elif cap is not None:
@@ -421,12 +415,12 @@ class Autoscaler:
                         k = min(excess, st.n_cached)
                     else:
                         continue
-                    target, scanned = self._find_migration_target(fn, node, k)
-                    scans += scanned
+                    target = targets.find(fn, node, k)
                     if target is None:
                         continue
                     node.evict_cached(fn, k)
                     target.add_cached(fn, k)
+                    targets.moved(node, target)
                     self._ledger.move(fn, node.id, target.id, k)
                     self.metrics.migrations += k
                     self.scheduler.notify_change(node, now)
@@ -434,23 +428,98 @@ class Autoscaler:
                     self.events.on_scale(now, fn, "migrate", k)
             if sp is not None:
                 sp.attrs.update(nodes_scanned=len(sources),
-                                target_scans=scans,
+                                target_scans=targets.scans,
+                                searches=targets.searches,
+                                skipped=targets.skipped,
+                                index_builds=targets.builds,
                                 moved=self.metrics.migrations - moved0)
 
-    def _find_migration_target(self, fn: str, src: Node, k: int
-                               ) -> Tuple[Optional[Node], int]:
-        """The busiest node hosting fn with room for k more, and the
-        number of candidates sorted to find it."""
-        cands = sorted(self.cluster.nodes_with(fn),
+
+#: the room of a candidate with unknown capacity, or that no longer hosts
+#: the function: below every k a search asks for (k >= 1)
+NO_ROOM = -math.inf
+
+
+class _TargetEntry:
+    """One function's candidates in search order, their rooms, and the
+    largest room."""
+
+    __slots__ = ("order", "pos", "rooms", "best")
+
+    def __init__(self, order: List[Node], rooms: List[float]):
+        self.order = order
+        self.pos = {n.id: i for i, n in enumerate(order)}
+        self.rooms = rooms
+        self.best = max(rooms, default=NO_ROOM)
+
+
+class _TargetIndex:
+    """Migration targets for one ``Autoscaler._migrate`` pass, indexed
+    per function at the function's first search.
+
+    ``find`` returns what a full scan returns: the first node other than
+    the source, in ``nodes_with(fn)`` stably sorted by descending
+    ``n_sat``, whose room ``min(capacity - n_sat - n_cached,
+    mem_headroom)`` is at least k.  The pass moves only cached
+    instances, so that order holds all pass; a move changes only its two
+    nodes' counts, so ``moved`` re-reads just their rooms (exact for a
+    ``platform.CapacityProvider`` whose answer changes only with the
+    node's counts).  A source that loses its last instance of fn drops
+    to ``NO_ROOM``; no node joins.  A k above the largest room is
+    answered without a walk.
+
+    Counters for the ``migrate`` span: ``searches``; ``skipped``, those
+    the largest room answered; ``builds``; ``scans``, candidates whose
+    room was read at a build or a refresh, or passed by a walk."""
+
+    def __init__(self, cluster: Cluster, capacity):
+        self.cluster = cluster
+        self.capacity = capacity
+        self._entries: Dict[str, _TargetEntry] = {}
+        self.searches = self.skipped = self.builds = self.scans = 0
+
+    def _room(self, node: Node, fn: str) -> float:
+        st = node.funcs.get(fn)
+        if st is None:
+            return NO_ROOM
+        self.scans += 1
+        cap = self.capacity.node_capacity(node, fn)
+        if cap is None:
+            return NO_ROOM
+        return min(cap - st.n_sat - st.n_cached,
+                   self.cluster.mem_headroom(node, fn))
+
+    def _build(self, fn: str) -> _TargetEntry:
+        order = sorted(self.cluster.nodes_with(fn),
                        key=lambda n: -n.funcs[fn].n_sat)
-        for node in cands:
-            if node.id == src.id:
-                continue
-            cap = self._node_capacity(node, fn)
-            if cap is None:
-                continue
-            st = node.funcs[fn]
-            if (cap - st.n_sat - st.n_cached >= k
-                    and self.cluster.mem_headroom(node, fn) >= k):
-                return node, len(cands)
-        return None, len(cands)
+        entry = _TargetEntry(order, [self._room(n, fn) for n in order])
+        self._entries[fn] = entry
+        self.builds += 1
+        return entry
+
+    def find(self, fn: str, src: Node, k: int) -> Optional[Node]:
+        self.searches += 1
+        entry = self._entries.get(fn) or self._build(fn)
+        if k > entry.best:
+            self.skipped += 1
+            return None
+        for i, (node, room) in enumerate(zip(entry.order, entry.rooms)):
+            if room >= k and node.id != src.id:
+                self.scans += i + 1
+                return node
+        self.scans += len(entry.order)
+        return None
+
+    def moved(self, src: Node, dst: Node) -> None:
+        """Re-read the rooms of a move's two nodes in every entry."""
+        for fn, entry in self._entries.items():
+            for node in (src, dst):
+                i = entry.pos.get(node.id)
+                if i is None:
+                    continue
+                old, new = entry.rooms[i], self._room(node, fn)
+                entry.rooms[i] = new
+                if new > entry.best:
+                    entry.best = new
+                elif new < old == entry.best:
+                    entry.best = max(entry.rooms)

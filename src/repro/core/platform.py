@@ -91,7 +91,13 @@ class CapacityProvider(Protocol):
     default (``autoscaler.SchedulerCapacityProvider``) reads the node's
     capacity table, then falls back to a zero-cost prediction-service
     cache hint; None means "unknown", and callers must never run
-    inference to find out (migration is not a critical path)."""
+    inference to find out (migration is not a critical path).
+
+    A provider's answer for a node may change only when that node's
+    instance counts change: a migration pass reads each candidate once
+    and re-reads only the nodes a move touched.  The default qualifies,
+    since the pass writes neither a capacity table nor the service
+    cache."""
 
     def node_capacity(self, node: Node, fn: str) -> Optional[int]:
         ...

@@ -1,11 +1,19 @@
 """Dual-staged scaling state machine: release timing, logical cold
 starts, keep-alive eviction, on-demand migration (paper §5, Fig 10)."""
+import copy
+import random
+import zlib
+
 import pytest
 
 from repro.core import (Autoscaler, Cluster, GroundTruth, JiaguScheduler,
                         PerfPredictor, ProfileStore, QoSStore,
                         ScalingConfig, generate_dataset,
                         synthetic_functions)
+from repro.core.cluster import CapEntry
+from repro.core.events import EventHub, Observer
+from repro.core.interference import NodeResources
+from repro.telemetry.spans import SpanTracer
 
 
 @pytest.fixture(scope="module")
@@ -141,3 +149,183 @@ def test_migration_frees_blocked_cached_instances(world):
     assert (aut.metrics.migrations > migrated_before
             or node.funcs[fn].n_cached == 0
             or aut.metrics.blocked_logical >= 0)
+
+
+# -- migration targets: the per-pass index against the full scan -----------
+
+
+class _ColocCapacity:
+    """A node's table entry where it has one, else an answer drawn from
+    the node's shape and counts, None for about a third of them: an
+    answer that changes only with the node's counts, as
+    ``CapacityProvider`` asks."""
+
+    def __init__(self, salt: int):
+        self.salt = salt
+
+    def node_capacity(self, node, fn):
+        entry = node.table.get(fn)
+        if entry is not None:
+            return entry.capacity
+        counts = sorted((g, s.n_sat, s.n_cached)
+                        for g, s in node.funcs.items() if s.total > 0)
+        h = zlib.crc32(repr((self.salt, fn, node.res.mem_mb,
+                             counts)).encode())
+        return None if h % 3 == 0 else h % 14
+
+
+class _NoCapacity:
+    """A table-free scheduler's provider."""
+
+    def node_capacity(self, node, fn):
+        return None
+
+
+class _Touched:
+    """Stands in for the scheduler: a migration pass only reports the
+    nodes it changed, source then target."""
+
+    def __init__(self):
+        self.ids = []
+
+    def notify_change(self, node, now):
+        self.ids.append(node.id)
+
+
+class _Migrations(Observer):
+    def __init__(self):
+        self.moves = []
+
+    def on_scale(self, now, fn, event, count):
+        if event == "migrate":
+            self.moves.append((fn, count))
+
+
+def _fleet(seed):
+    """A fleet of mixed node shapes with saturated and cached instances,
+    some all-cached nodes, and capacity tables on most (node, fn)."""
+    rng = random.Random(seed)
+    specs = synthetic_functions(4, seed=seed)
+    shapes = [NodeResources(cpu_mcores=48_000.0, mem_mb=8192.0),
+              NodeResources(cpu_mcores=96_000.0, mem_mb=16384.0),
+              NodeResources(cpu_mcores=32_000.0, mem_mb=6144.0)]
+    cluster = Cluster(specs, res_pool=shapes)
+    for _ in range(rng.randint(6, 30)):
+        node = cluster.add_node()
+        all_cached = rng.random() < 0.25
+        for fn in rng.sample(sorted(specs), rng.randint(1, 3)):
+            sat = 0 if all_cached else rng.randint(0, 4)
+            cached = rng.randint(0 if sat else 1, 3)
+            if sat:
+                node.deploy(fn, sat)
+            if cached:
+                node.add_cached(fn, cached)
+            if rng.random() < 0.7:
+                node.table[fn] = CapEntry(
+                    capacity=rng.randint(max(sat - 1, 0), sat + cached + 8))
+    return cluster
+
+
+def _counts(cluster):
+    return {nid: {fn: (s.n_sat, s.n_cached) for fn, s in n.funcs.items()}
+            for nid, n in cluster.nodes.items()}
+
+
+def _room(cluster, capacity, node, fn):
+    cap = capacity.node_capacity(node, fn)
+    if cap is None:
+        return -1
+    st = node.funcs[fn]
+    return min(cap - st.n_sat - st.n_cached, cluster.mem_headroom(node, fn))
+
+
+def _full_scan_moves(cluster, capacity, seen):
+    """The migration pass with the search the index replaced: each search
+    sorts every node hosting fn by descending n_sat and reads each
+    candidate's capacity until one fits.  Returns the moves and the
+    searches whose k exceeds every candidate's room; ``seen`` counts the
+    cases the fleets must cover."""
+    moves, filled, gone, beyond = [], {}, set(), 0
+    for node in cluster.nodes_with_cached():
+        all_cached = all(s.n_sat == 0 for s in node.funcs.values()) \
+            and node.n_instances() > 0
+        seen["all_cached"] += all_cached
+        for fn, st in list(node.funcs.items()):
+            if st.n_cached == 0:
+                continue
+            cap = capacity.node_capacity(node, fn)
+            if all_cached:
+                k = st.n_cached
+            elif cap is not None:
+                if st.n_sat + st.n_cached - cap <= 0:
+                    continue
+                k = min(st.n_sat + st.n_cached - cap, st.n_cached)
+            else:
+                continue
+            seen["searched_after_leaving"] += fn in gone
+            cands = sorted(cluster.nodes_with(fn),
+                           key=lambda n: -n.funcs[fn].n_sat)
+            beyond += k > max(
+                (_room(cluster, capacity, c, fn) for c in cands),
+                default=-1)
+            target = None
+            for cand in cands:
+                if cand.id == node.id:
+                    continue
+                c = capacity.node_capacity(cand, fn)
+                if c is None:
+                    continue
+                s = cand.funcs[fn]
+                room = c - s.n_sat - s.n_cached
+                if room < k:
+                    seen["filled_earlier"] += filled.get((fn, cand.id),
+                                                         0) >= k
+                    continue
+                if cluster.mem_headroom(cand, fn) < k:
+                    seen["memory_bound"] += 1
+                    continue
+                target = cand
+                filled[(fn, cand.id)] = room
+                break
+            if target is None:
+                continue
+            node.evict_cached(fn, k)
+            target.add_cached(fn, k)
+            if fn not in node.funcs:
+                gone.add(fn)
+            moves.append((fn, node.id, target.id, k))
+    return moves, beyond
+
+
+def test_migration_index_matches_the_full_scan():
+    seen = dict.fromkeys(("all_cached", "searched_after_leaving",
+                          "filled_earlier", "memory_bound"), 0)
+    searched = moved = 0
+    for seed in range(50):
+        table_free = seed % 5 == 0
+        capacity = _NoCapacity() if table_free else _ColocCapacity(seed)
+        fleet = _fleet(seed)
+        oracle = copy.deepcopy(fleet)
+        want, beyond = _full_scan_moves(oracle, capacity, seen)
+
+        touched, log = _Touched(), _Migrations()
+        aut = Autoscaler(fleet, touched, ScalingConfig(), capacity=capacity,
+                         events=EventHub([log]))
+        aut.tracer = SpanTracer()
+        aut._migrate(0.0)
+        got = [(fn, touched.ids[2 * i], touched.ids[2 * i + 1], k)
+               for i, (fn, k) in enumerate(log.moves)]
+        assert got == want, seed
+        assert _counts(fleet) == _counts(oracle), seed
+        span, = aut.tracer.spans
+        a = span.attrs
+        assert a["moved"] == sum(k for *_, k in want)
+        assert a["skipped"] == beyond
+        assert a["skipped"] <= a["searches"]
+        assert a["index_builds"] <= a["searches"]
+        if table_free:
+            assert want == [] and a["skipped"] == a["searches"]
+        searched += a["searches"]
+        moved += len(want)
+    assert searched > 0 and moved > 0
+    assert all(seen.values()), seen

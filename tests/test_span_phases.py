@@ -78,6 +78,11 @@ def test_storm_run_records_every_phase_with_its_counters(storm_spans):
     assert res.scaling.migrations > 0
     assert any(s.attrs["nodes_scanned"] > 0 for s in mig)
     assert any(s.attrs["target_scans"] > 0 for s in mig)
+    for s in mig:
+        a = s.attrs
+        assert a["skipped"] <= a["searches"]
+        assert a["index_builds"] <= a["searches"]
+    assert any(s.attrs["searches"] > 0 for s in mig)
     place = [s for s in spans if s.name == "place"]
     assert sum(s.attrs["placed"] for s in place) == \
         res.scaling.real_cold_starts
