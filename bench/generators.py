@@ -6,14 +6,21 @@ Copied from ``repro.core.traces`` (``burst_storm_trace``,
 ``scale_trace_to_nodes`` and the population half of ``make_scenario``)
 so that a later change to the program cannot change what the benchmark
 offers it.  ``bench/tests/test_generators.py`` holds them equal to the
-program's for the same seed and parameters.
+program's for the same seed and parameters, but for the departures
+below.
 
 Everything here is plain numpy: series are ``{name: (T,) float64}`` and
 functions are ``{name: {field: value}}`` with the fields of
-``repro.core.profiles.FunctionSpec``.  The one departure is
-``burst_storm(period_s=..., width_s=..., amp=...)``, which starts one
-storm of a fixed width and height every period instead of at Poisson
-times, and otherwise draws exactly what the original draws.
+``repro.core.profiles.FunctionSpec``.  The departures:
+
+  * ``burst_storm(period_s=..., width_s=..., amp=...)`` starts one
+    storm of a fixed width and height every period instead of at
+    Poisson times, and otherwise draws exactly what the original draws;
+  * ``azure_sparse`` gives its head a period of a day
+    (``_head_period``), where the original draws one, and given
+    ``scale_rps`` makes the most popular functions its head, where the
+    original takes the first names; over the names in that order it
+    draws what the original draws.
 """
 from __future__ import annotations
 
@@ -134,18 +141,42 @@ def burst_storm(fn_names: List[str], duration_s: int = 3600, seed: int = 0,
     return out
 
 
+#: the head's diurnal period: a day, as in the Azure Functions trace
+#: (Shahrad et al., "Serverless in the Wild", USENIX ATC 2020)
+DAY_S = 86400.0
+
+
+def _head_period(drawn: float) -> float:
+    """A head function's period: a day, in place of the original's draw
+    from 1,500-3,600 s, which is still made so that no later draw
+    moves."""
+    return DAY_S
+
+
 def azure_sparse(fn_names: List[str], duration_s: int = 3600, seed: int = 0,
                  scale_rps: Optional[Dict[str, float]] = None,
                  hot_frac: float = 0.1, zipf_s: float = 1.5) -> Series:
     """A hot head with diurnal load and a Zipf long tail of sparse,
-    few-second invocation episodes at Poisson times."""
+    few-second invocation episodes at Poisson times.
+
+    Shaped on the Azure Functions trace (Shahrad et al., "Serverless in
+    the Wild", USENIX ATC 2020): invocations concentrate in the most
+    popular functions, and their load follows a daily cycle: the head's
+    period is ``DAY_S``.  With ``scale_rps`` the head is the
+    ``hot_frac`` of functions with the largest shares (ties broken by
+    name) and tail rank 1 is the largest share left, so the tail's
+    episode rate follows popularity; without it the head is the first
+    names."""
     rng = np.random.default_rng(seed)
     t = np.arange(duration_s, dtype=np.float64)
     n_hot = max(1, int(round(hot_frac * len(fn_names))))
+    order = list(fn_names)
+    if scale_rps is not None:
+        order.sort(key=lambda fn: (-scale_rps.get(fn, 0.0), fn))
     out = {}
-    for i, fn in enumerate(fn_names):
+    for i, fn in enumerate(order):
         if i < n_hot:
-            period = rng.uniform(1500, 3600)
+            period = _head_period(rng.uniform(1500, 3600))
             phase = rng.uniform(0, 2 * math.pi)
             shape = (0.45 + 0.35 * np.sin(2 * math.pi * t / period + phase)
                      ) * rng.lognormal(0, 0.2, duration_s)
@@ -162,7 +193,7 @@ def azure_sparse(fn_names: List[str], duration_s: int = 3600, seed: int = 0,
             e = min(s + int(rng.uniform(2, 8)), duration_s)
             series[s:e] = peak * rng.uniform(0.5, 1.0)
         out[fn] = series
-    return out
+    return {fn: out[fn] for fn in fn_names}
 
 
 #: trace programs a traffic file may name under ``"generator"``

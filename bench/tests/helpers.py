@@ -23,10 +23,11 @@ def _frozen(config_json: str) -> str:
                                str(Path(where) / "world.npz"))
 
 
-def tiny_config(name="jiagu-1k"):
-    """The configuration at a test's size, with its own frozen world."""
+def tiny_config(name="jiagu-1k", **sizes):
+    """The configuration at a test's size (``sizes`` overrides its
+    top-level keys), with its own frozen world."""
     cfg = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    cfg.update(target_nodes=24, n_functions=8)
+    cfg.update({"target_nodes": 24, "n_functions": 8, **sizes})
     cfg["prediction"].update(n_train=300, n_trees=8, max_depth=6)
     cfg["world_file"] = _frozen(json.dumps(cfg, sort_keys=True))
     return cfg
