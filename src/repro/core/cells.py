@@ -105,7 +105,12 @@ class CellRouter:
 
     With a single cell ``split`` returns the global RPS dict untouched:
     no float division ever runs, which is what keeps ``cells=1``
-    bit-identical to the legacy loop."""
+    bit-identical to the legacy loop.
+
+    ``counts``, when given (the ``route`` span's attributes), receives
+    the plan's counters: ``fns`` routed, ``homed`` (cold, sent whole to
+    their home cell), ``split`` (served by more than one cell) and
+    ``spilled`` (rate past every hosting cell's ``load_cap``)."""
 
     def __init__(self, cells: Sequence[Cell], load_cap: float = 0.85):
         self.cells = list(cells)
@@ -115,19 +120,27 @@ class CellRouter:
         return zlib.crc32(fn.encode()) % len(self.cells)
 
     def split(self, rps: Dict[str, float],
-              specs: Dict[str, FunctionSpec]) -> List[Dict[str, float]]:
+              specs: Dict[str, FunctionSpec],
+              counts: Optional[Dict[str, int]] = None
+              ) -> List[Dict[str, float]]:
         cells = self.cells
         if len(cells) == 1:
+            if counts is not None:
+                counts.update(fns=sum(v > 1e-9 for v in rps.values()),
+                              homed=0, split=0, spilled=0)
             return [rps]
         shares: List[Dict[str, float]] = [{} for _ in cells]
         inst_totals = [c.cluster.total_instances() for c in cells]
+        n_fns = n_homed = n_split = n_spilled = 0
         for fn, fn_rps in rps.items():
             if fn_rps <= 1e-9:
                 continue
+            n_fns += 1
             sats = [c.cluster.sat_count(fn) for c in cells]
             total_sat = sum(sats)
             if total_sat == 0:
                 shares[self.home(fn)][fn] = fn_rps
+                n_homed += 1
                 continue
             spec = specs[fn]
 
@@ -145,11 +158,18 @@ class CellRouter:
                 take_by[i] = take
                 remaining -= take
             if remaining > 1e-9:
+                n_spilled += 1
                 for i in order:
                     take_by[i] += remaining * sats[i] / total_sat
+            served = 0
             for i, take in take_by.items():
                 if take > 1e-12:
                     shares[i][fn] = take
+                    served += 1
+            n_split += served > 1
+        if counts is not None:
+            counts.update(fns=n_fns, homed=n_homed, split=n_split,
+                          spilled=n_spilled)
         return shares
 
 
@@ -158,12 +178,15 @@ class CapacityExchange:
     ``PredictionService`` solves is offered to every sibling service
     (``accept_exchange`` — epoch-checked, silently dropped across a
     retrain boundary), replacing the global capacity table the legacy
-    single-service world shared for free."""
+    single-service world shared for free.  ``published`` counts the
+    capacities offered, ``fanout`` the offers made to siblings and
+    ``accepted`` those a sibling took into its cache."""
 
     def __init__(self):
         self.services: List = []
         self.published = 0
         self.fanout = 0
+        self.accepted = 0
 
     def join(self, service) -> None:
         self.services.append(service)
@@ -173,7 +196,8 @@ class CapacityExchange:
         self.published += 1
         for svc in self.services:
             if svc is not src:
-                svc.accept_exchange(key, epoch, cap)
+                if svc.accept_exchange(key, epoch, cap):
+                    self.accepted += 1
                 self.fanout += 1
 
 
@@ -287,13 +311,19 @@ class CellSimulation:
         for t in range(T):
             now = float(t)
             rps = {fn: self.trace.at(fn, t) for fn in self.trace.rps}
-            shares = self.cell_router.split(rps, self.specs)
+            with self.tracer.phase("route") as sp:
+                shares = self.cell_router.split(
+                    rps, self.specs, None if sp is None else sp.attrs)
+            ex = self.exchange
             with self.tracer.span("schedule") as sp:
                 if sp is not None:
                     d0 = sum(c.scheduler.metrics.decisions
                              for c in self.cells)
                     p0 = sum(c.scheduler.metrics.instances_placed
                              for c in self.cells)
+                    idle0 = self.idle_cell_ticks
+                    if ex is not None:
+                        pub0, acc0 = ex.published, ex.accepted
                 for cell, cell_rps in zip(self.cells, shares):
                     self._tick_cell(cell, now, cell_rps)
                 if sp is not None:
@@ -304,6 +334,11 @@ class CellSimulation:
                     sp.attrs["placed"] = sum(
                         c.scheduler.metrics.instances_placed
                         for c in self.cells) - p0
+                    sp.attrs["cells"] = len(self.cells)
+                    sp.attrs["cells_idle"] = self.idle_cell_ticks - idle0
+                    if ex is not None:
+                        sp.attrs["published"] = ex.published - pub0
+                        sp.attrs["accepted"] = ex.accepted - acc0
             for cell, cell_rps in zip(self.cells, shares):
                 self._measure_cell(cell, now, cell_rps, res)
             if (self.cfg.collect_samples and self.predictor is not None

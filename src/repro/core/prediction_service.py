@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
@@ -505,6 +505,9 @@ class PredictionService:
         #: sibling cells' services can serve it cache-warm.  None (the
         #: default) is zero-overhead.
         self.exchange = None
+        #: keys of ``_cache`` whose entry a sibling published (dropped
+        #: with the entry); ``solve.lookup`` counts hits on them
+        self._exchanged: Set[SigKey] = set()
         if self.cfg.learned_shape_margin and predictor.fitted:
             self.shape_margins()
 
@@ -585,6 +588,7 @@ class PredictionService:
         state the signatures cannot see has changed)."""
         if self._cache:
             self._cache.clear()
+            self._exchanged.clear()
         self._shape_margins = None   # re-learn against the new forest
         self.stats.cache_epochs += 1
 
@@ -608,34 +612,44 @@ class PredictionService:
         if epoch != self._epoch:
             self.stats.stale_epoch_hits += 1
             del self._cache[key]
+            self._exchanged.discard(key)
             return None
         return cap
 
-    def _cache_put(self, key: SigKey, cap: int):
-        """Insert one solved capacity, evicting oldest-first (dict
-        insertion order) at ``max_cache_entries`` — the wholesale
-        ``clear()`` this replaces dropped every warm entry the moment
-        the bound was hit, triggering a cluster-wide re-solve storm."""
-        if not self.cfg.cache:
-            return
+    def _make_room(self, key: SigKey) -> None:
+        """Evict oldest-first (dict insertion order) until ``key`` fits
+        within ``max_cache_entries`` — the wholesale ``clear()`` this
+        replaces dropped every warm entry the moment the bound was hit,
+        triggering a cluster-wide re-solve storm."""
         if key not in self._cache:
             while len(self._cache) >= self.cfg.max_cache_entries:
-                self._cache.pop(next(iter(self._cache)))
+                old = next(iter(self._cache))
+                del self._cache[old]
+                self._exchanged.discard(old)
+
+    def _cache_put(self, key: SigKey, cap: int):
+        """Insert one solved capacity (see ``_make_room``) and publish
+        it to the exchange, if joined.  A key solved here is never one
+        a sibling published: that entry would have been a hit, so it
+        was evicted, and its origin with it."""
+        if not self.cfg.cache:
+            return
+        self._make_room(key)
         self._cache[key] = (self._epoch, cap)
         if self.exchange is not None:
             self.exchange.publish(self, key, self._epoch, cap)
 
-    def accept_exchange(self, key: SigKey, epoch: int, cap: int):
-        """Receive a capacity solved by a sibling cell's service.  Only
-        same-epoch entries are accepted (all cells share one forest, so
-        epochs agree except transiently around a retrain) and the entry
-        lands without re-publishing."""
+    def accept_exchange(self, key: SigKey, epoch: int, cap: int) -> bool:
+        """Receive a capacity solved by a sibling cell's service; returns
+        whether it was taken.  Only same-epoch entries are accepted (all
+        cells share one forest, so epochs agree except transiently
+        around a retrain) and the entry lands without re-publishing."""
         if not self.cfg.cache or epoch != self._epoch:
-            return
-        if key not in self._cache:
-            while len(self._cache) >= self.cfg.max_cache_entries:
-                self._cache.pop(next(iter(self._cache)))
+            return False
+        self._make_room(key)
         self._cache[key] = (epoch, cap)
+        self._exchanged.add(key)
+        return True
 
     def shape_margins(self) -> Dict[Tuple[float, ...], float]:
         """Per-shape QoS margins learned from per-shape *validation*
@@ -756,6 +770,7 @@ class PredictionService:
         assignment: List[Optional[SigKey]] = [None] * len(norm)
         with self.tracer.phase("solve.lookup") as sp:
             hits0, dupes0 = self.stats.cache_hits, self.stats.coalesced_dupes
+            exchanged = 0
             for i, (coloc, fn, m_max, node_res) in enumerate(norm):
                 key = self.signature(coloc, fn, m_max, node_res)
                 if self.cfg.cache:
@@ -763,6 +778,8 @@ class PredictionService:
                     if cap is not None:
                         results[i] = (cap, 0)
                         self.stats.cache_hits += 1
+                        if sp is not None:
+                            exchanged += key in self._exchanged
                         continue
                 if key in unique:
                     self.stats.coalesced_dupes += 1
@@ -778,6 +795,8 @@ class PredictionService:
                     queries=len(norm), unique=len(unique),
                     cache_hits=self.stats.cache_hits - hits0,
                     dupes=self.stats.coalesced_dupes - dupes0)
+                if self.exchange is not None:
+                    sp.attrs["exchanged_hits"] = exchanged
 
         if self.cfg.drain == "device":
             self._sweep_device(list(unique.values()))

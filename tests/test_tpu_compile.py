@@ -59,19 +59,28 @@ def test_forest_apply_compiles_for_v5e(one_chip, T, F, N):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("T,F,S,R", [(24, 31, 3, 6), (32, 33, 1024, 8),
-                                     (24, 33, 1025, 6),
-                                     # the widest launch a 24-function
-                                     # platform warms (R bucket 32)
-                                     (24, 33, 128, 32)])
-def test_capacity_sweep_compiles_for_v5e(one_chip, T, F, S, R):
-    M = 16
+def _assert_sweep_compiles(one_chip, T, F, S, M, R):
     x = jax.ShapeDtypeStruct((S, M, R, F), jnp.float32, sharding=one_chip)
     lim = jax.ShapeDtypeStruct((S, M, R), jnp.float32, sharding=one_chip)
     compiled = jax.jit(
         lambda *a: rfr_capacity_sweep(*a, interpret=False)).lower(
         x, lim, *_forest(one_chip, T)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T,F,S,R", [(24, 31, 3, 6), (32, 33, 1024, 8),
+                                     (24, 33, 1025, 6),
+                                     # the widest launch a 24-function
+                                     # platform warms (R bucket 32)
+                                     (24, 33, 128, 32)])
+def test_capacity_sweep_compiles_for_v5e(one_chip, T, F, S, R):
+    _assert_sweep_compiles(one_chip, T, F, S, 16, R)
+
+
+def test_widest_64_function_launch_compiles_for_v5e(one_chip):
+    """The widest launch ``warm_device`` compiles for the 64-function
+    ``jiagu-4k`` configuration: M bucket 32 (m_max 24), R bucket 64."""
+    _assert_sweep_compiles(one_chip, 24, 31, 128, 32, 64)
 
 
 def test_sweep_kernel_op_keeps_its_name(one_chip):
